@@ -33,6 +33,7 @@ from repro.distributed.cannon import (
     gather_c,
     make_cannon_runner,
 )
+from repro.launch.mesh import auto_mesh
 
 
 def main() -> None:
@@ -42,7 +43,7 @@ def main() -> None:
     # a square device grid makes the inner level a real shard_map Cannon;
     # otherwise the 1×1 grid's inner program is the local device matmul
     n_grid = 2 if len(jax.devices()) >= 4 else 1
-    mesh = (jax.make_mesh((n_grid, n_grid), ("data", "model"))
+    mesh = (auto_mesh((n_grid, n_grid), ("data", "model"))
             if n_grid > 1 else None)
 
     rng = np.random.default_rng(0)

@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.engine import ServeEngine
 from repro.models import model as M
 
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=True)
     params = M.init_params(cfg, jax.random.PRNGKey(0))

@@ -50,7 +50,14 @@ from repro.core.plan import (
     enumerate_plans,
     host_plan,
 )
-from repro.core.roofline import TPU_V5E, HardwareSpec, RooflineReport, analyze
+from repro.core.roofline import (
+    PEAKS,
+    TPU_V5E,
+    HardwareSpec,
+    RooflineReport,
+    analyze,
+    hardware_spec,
+)
 from repro.core.stream import Stream, StreamSet
 
 __all__ = [
@@ -64,6 +71,7 @@ __all__ = [
     "CompiledHyperstepProgram", "HyperstepRecord", "HyperstepRunner", "run_bsps",
     "CompiledSchedule", "PlanChoice", "ScratchSpec", "StreamPlan", "TokenSpec",
     "autotune", "enumerate_plans", "host_plan",
-    "TPU_V5E", "HardwareSpec", "RooflineReport", "analyze",
+    "PEAKS", "TPU_V5E", "HardwareSpec", "RooflineReport", "analyze",
+    "hardware_spec",
     "Stream", "StreamSet",
 ]

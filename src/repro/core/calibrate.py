@@ -117,9 +117,21 @@ def measure_hyperstep_latency() -> float:
     return float(np.median([r.step_seconds for r in recs]))
 
 
+def _local_words(dev) -> int:
+    """Local memory L of ``dev`` in f32 words.
+
+    The device's own memory where the runtime reports it (an accelerator's
+    HBM: the KV pool and the weights live there), else 32 MiB, about a host
+    CPU's last-level cache.
+    """
+    stats = dev.memory_stats() or {}
+    return int(stats.get("bytes_limit", 1 << 25)) // 4
+
+
 def calibrate(p: int = 1, *, fast: bool = False) -> BSPAccelerator:
     """Measure (r, e, l) and return the pack. ``fast=True`` shrinks the probes
-    and skips the latency run — good enough for a launcher's predicted row."""
+    and skips the latency run — good enough for a launcher's predicted row.
+    The pack is named after the device it measured."""
     if fast:
         r = measure_flops_rate(n=256)
         words_per_s = measure_external_bandwidth(nbytes=1 << 22)
@@ -129,10 +141,11 @@ def calibrate(p: int = 1, *, fast: bool = False) -> BSPAccelerator:
         words_per_s = measure_external_bandwidth()
         l = measure_hyperstep_latency() * r
     e = r / words_per_s  # FLOPs per word
+    dev = jax.devices()[0]
     return BSPAccelerator(
         p=p, g=0.0, l=l, r=r, e=e,
-        L=(1 << 25) // 4, E=(1 << 34) // 4,  # ~L3-ish local, RAM external
-        word_bytes=4, name="container-host",
+        L=_local_words(dev), E=(1 << 34) // 4,  # RAM external
+        word_bytes=4, name=f"calibrated:{dev.platform}:{dev.device_kind}",
     )
 
 
@@ -150,8 +163,6 @@ def measure_host_superstep(mesh, axis: str = "host") -> tuple[float, float]:
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     n = int(mesh.shape[axis])
     if n <= 1:
         return 0.0, 0.0
@@ -160,10 +171,10 @@ def measure_host_superstep(mesh, axis: str = "host") -> tuple[float, float]:
     def timed_psum(words: int) -> float:
         x = jnp.zeros((n * words,), jnp.float32)
         x = jax.device_put(x, NamedSharding(mesh, P((axis,))))
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda v: jax.lax.psum(v, axis),
             mesh=mesh, in_specs=P((axis,)), out_specs=P(None),
-            check_rep=False))
+            check_vma=False))
         return _time(lambda: jax.block_until_ready(f(x)), repeats=7)
 
     t1, t2 = timed_psum(w1), timed_psum(w2)

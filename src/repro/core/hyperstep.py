@@ -345,8 +345,8 @@ class CompiledHyperstepProgram:
     """A whole hyperstep program lowered to one donated jitted ``lax.scan``.
 
     Built by :meth:`HyperstepRunner.compile`; ``__call__(state, out_bufs,
-    stacked)`` runs ``total`` hypersteps in a single device dispatch and
-    returns ``(state, out_bufs)``. ``schedule`` exposes the precomputed
+    stacked, operands)`` runs ``total`` hypersteps in a single device
+    dispatch and returns ``(state, out_bufs)``. ``schedule`` exposes the precomputed
     gather/scatter index arrays (tests validate them against
     :meth:`repro.core.plan.StreamPlan.compiled_schedule`).
     """
@@ -355,8 +355,9 @@ class CompiledHyperstepProgram:
     schedule: _RunSchedule
     _call: Callable[..., Any]
 
-    def __call__(self, state: Any, out_bufs: Any, stacked: Any) -> Any:
-        return self._call(state, out_bufs, stacked)
+    def __call__(self, state: Any, out_bufs: Any, stacked: Any,
+                 operands: Any = None) -> Any:
+        return self._call(state, out_bufs, stacked, operands)
 
 
 class HyperstepRunner:
@@ -865,7 +866,9 @@ class HyperstepRunner:
             "f": jnp.asarray(sched.flush_mask),
         }
 
-        def program(state: Any, out_bufs: Any, stacked: Any) -> Any:
+        def program(state: Any, out_bufs: Any, stacked: Any,
+                    operands: Any) -> Any:
+            extra = () if operands is None else (operands,)
             residents = [
                 [None if rates[i] > 0 else jax.tree_util.tree_map(
                     lambda leaf, c=c, i=i: leaf[res_idx[c, i]], stacked[c][i])
@@ -886,7 +889,7 @@ class HyperstepRunner:
                                 _gather_block(stacked[c][i], x["g"][c, a_j], r))
                             a_j += 1
                     per_core.append(toks)
-                out = step(state, self._step_tokens(per_core))
+                out = step(state, self._step_tokens(per_core), *extra)
                 if n_out:
                     state, out_tokens = out
                     bufs = [
@@ -907,7 +910,22 @@ class HyperstepRunner:
 
         return jax.jit(program, donate_argnums=(0, 1) if donate else ())
 
-    def _run_compiled(self, state: Any, num_hypersteps: int | None) -> Any:
+    def lower(self, state: Any, num_hypersteps: int | None = None, *,
+              operands: Any = None) -> Any:
+        """Lower the compiled program for these arguments without running it.
+
+        ``.compile().memory_analysis()`` of the result gives the program's
+        device memory: arguments, outputs, donated aliases and temporaries.
+        """
+        total = self._resolve_total(num_hypersteps)
+        prog = self._compiled_cache.get(total) or self.compile(total)
+        stacked = [[s.as_stacked() for s in ss] for ss in self._streams]
+        out_bufs = [[s.as_stacked() for s in outs]
+                    for outs in self._out_streams]
+        return prog._call.lower(state, out_bufs, stacked, operands)
+
+    def _run_compiled(self, state: Any, num_hypersteps: int | None,
+                      operands: Any = None) -> Any:
         total = self._resolve_total(num_hypersteps)
         if total <= 0:
             return state
@@ -954,7 +972,7 @@ class HyperstepRunner:
             stage_s = time.perf_counter() - t0
 
             t1 = time.perf_counter()
-            state, out_bufs = prog(state, out_bufs, stacked)
+            state, out_bufs = prog(state, out_bufs, stacked, operands)
             state = _block(state)
             out_bufs = _block(out_bufs)
             if self.faults is not None:
@@ -1038,7 +1056,8 @@ class HyperstepRunner:
         return state
 
     def run(self, state: Any, num_hypersteps: int | None = None, *,
-            compiled: bool = False, measure: bool = True) -> Any:
+            compiled: bool = False, measure: bool = True,
+            operands: Any = None) -> Any:
         """Execute hypersteps until streams are exhausted (or a fixed count).
 
         Callable repeatedly: closing the streams on exit rewinds their
@@ -1051,9 +1070,16 @@ class HyperstepRunner:
         dispatches pipeline and the per-step compute timings are dispatch
         times, not device times (records are still appended; use
         ``measure=True`` when the timings matter).
+
+        ``operands`` are read-only inputs of every hyperstep (model weights):
+        when given, the step is called as ``step(state, tokens, operands)``.
+        A compiled run takes them as an argument it neither donates nor
+        returns, so the program holds one copy of them rather than carrying
+        them through the scan.
         """
         if compiled:
-            return self._run_compiled(state, num_hypersteps)
+            return self._run_compiled(state, num_hypersteps, operands)
+        extra = () if operands is None else (operands,)
         ncores = self.num_cores
         # One background lane per core, like the single DMA engine per
         # Epiphany core; per-run so the runner can be reused afterwards.
@@ -1162,7 +1188,7 @@ class HyperstepRunner:
                         ]
 
                 t_c = time.perf_counter()
-                out = self._step(state, step_toks)
+                out = self._step(state, step_toks, *extra)
                 if n_out:
                     state, out_tokens = out
                 else:

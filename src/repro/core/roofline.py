@@ -16,7 +16,8 @@ with (per the assignment's definitions, global quantities over ``chips``):
 *per-device* numbers (the partitioned module), so per-device values × chips give
 the globals; the two normalisations cancel and we work per-device directly.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware constants come from :data:`PEAKS`, keyed by the ``device_kind`` JAX
+reports; :func:`hardware_spec` refuses a kind that is not in the table.
 """
 
 from __future__ import annotations
@@ -26,7 +27,8 @@ from typing import Any
 
 from repro.core.hlo import CollectiveStats, collective_bytes
 
-__all__ = ["HardwareSpec", "TPU_V5E", "RooflineReport", "analyze", "model_flops"]
+__all__ = ["HardwareSpec", "PEAKS", "TPU_V5E", "RooflineReport", "analyze",
+           "hardware_spec", "model_flops"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,14 +45,32 @@ class HardwareSpec:
         return self.ici_bandwidth * self.ici_links
 
 
-TPU_V5E = HardwareSpec(
-    name="tpu-v5e",
-    peak_flops=197e12,
-    hbm_bandwidth=819e9,
-    ici_bandwidth=50e9,
-    ici_links=2,
-    hbm_bytes=16e9,
-)
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: TPU v5e (kind "TPU v5 lite"): Google Cloud documentation, "TPU v5e" —
+#: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip
+#: interconnect (taken here as 2 links × 50 GB/s per direction).
+PEAKS: dict[str, HardwareSpec] = {
+    "TPU v5 lite": HardwareSpec(
+        name="tpu-v5e",
+        peak_flops=197e12,
+        hbm_bandwidth=819e9,
+        ici_bandwidth=50e9,
+        ici_links=2,
+        hbm_bytes=16e9,
+    ),
+}
+
+TPU_V5E = PEAKS["TPU v5 lite"]
+
+
+def hardware_spec(device_kind: str) -> HardwareSpec:
+    """The peaks of ``device_kind``; an unlisted kind is an error, not a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known kinds: {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,7 +86,7 @@ class RooflineReport:
     coll_stats: CollectiveStats | None
     # model-level useful FLOPs (global): 6·N·D dense / 6·N_active·D MoE
     model_flops_global: float
-    hw: HardwareSpec = TPU_V5E
+    hw: HardwareSpec
     # peak memory from compiled.memory_analysis(), bytes per device
     peak_device_bytes: float = 0.0
 
@@ -136,10 +156,7 @@ class RooflineReport:
 
 
 def _cost_dict(compiled: Any) -> dict[str, float]:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0]
-    return ca
+    return compiled.cost_analysis()
 
 
 def _peak_bytes(compiled: Any) -> float:
@@ -162,7 +179,7 @@ def analyze(
     *,
     chips: int,
     model_flops_global: float,
-    hw: HardwareSpec = TPU_V5E,
+    hw: HardwareSpec,
 ) -> RooflineReport:
     """Build a :class:`RooflineReport` from a jax ``lowered``/``compiled`` pair."""
     cost = _cost_dict(compiled)

@@ -33,7 +33,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pvary, shard_map
 from repro.core.hyperstep import HyperstepRunner
 from repro.core.plan import ScratchSpec, StreamPlan, TokenSpec
 from repro.core.stream import Stream, StreamSet
@@ -89,7 +88,7 @@ def cannon_matmul(
         b_blk = jax.lax.fori_loop(0, n - 1, shift_b, b_blk)
 
         acc = jnp.zeros((a_blk.shape[0], b_blk.shape[1]), jnp.float32)
-        acc = pvary(acc, (axis_a, axis_b))  # mark device-varying for scan
+        acc = jax.lax.pcast(acc, (axis_a, axis_b), to="varying")  # for the scan carry
 
         def step(_, carry):
             acc, a_blk, b_blk = carry
@@ -101,7 +100,7 @@ def cannon_matmul(
         acc, a_blk, b_blk = jax.lax.fori_loop(0, n, step, (acc, a_blk, b_blk))
         return acc.astype(a_blk.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_a, axis_b), P(axis_a, axis_b)),

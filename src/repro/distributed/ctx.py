@@ -63,6 +63,15 @@ def constrain(x: jax.Array, *spec) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, P(*clean))
 
 
+def sharded() -> bool:
+    """True while a mesh of more than one device is registered.
+
+    Such programs are partitioned by GSPMD, which cannot split a Pallas
+    kernel (a Mosaic custom call): model code then takes its XLA path.
+    """
+    return any(n > 1 for n in _AXES.values())
+
+
 def dp_size() -> int:
     """Product of registered data-parallel axis sizes (1 without a mesh)."""
     n = 1

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pvary, shard_map
-
 __all__ = ["pipeline_apply"]
 
 
@@ -44,8 +42,8 @@ def pipeline_apply(
         p_stage = jax.tree_util.tree_map(lambda t: t[0], params_local)
         buf = jnp.zeros_like(xs[0])
         outs = jnp.zeros_like(xs)
-        buf = pvary(buf, (axis,))
-        outs = pvary(outs, (axis,))
+        buf = jax.lax.pcast(buf, (axis,), to="varying")
+        outs = jax.lax.pcast(outs, (axis,), to="varying")
 
         def tick(t, carry):
             buf, outs = carry
@@ -73,5 +71,5 @@ def pipeline_apply(
                                is_leaf=lambda x: hasattr(x, "shape")),
         P(),
     )
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P())(
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P())(
         stage_params, microbatches)

@@ -216,6 +216,7 @@ def flash_attention(
             causal=causal, sm_scale=sm_scale, q_offset=q_offset,
         ),
         interpret=interpret,
+        vma=pipeline.operand_vma(q, k, v),
     )(q, k, v)
     if pad_q:
         out = out[:, :, :sq, :]

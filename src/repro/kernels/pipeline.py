@@ -16,7 +16,7 @@ mapping is mechanical (DESIGN.md §3):
   TokenSpec.rate 0 (resident)    constant index map (fetched once)
   output TokenSpec.full_shape    out_shape=jax.ShapeDtypeStruct(...)
   ScratchSpec                    pltpu.VMEM scratch ref
-  dimension_semantics            compiler params (via the compat shim)
+  dimension_semantics            pltpu.CompilerParams
   =============================  ==========================================
 
 Mosaic drains a finished output block's VMEM→HBM copy while the next grid
@@ -41,10 +41,9 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.plan import StreamPlan
 
-__all__ = ["lower", "lower_cache_clear", "lower_cache_info"]
+__all__ = ["lower", "lower_cache_clear", "lower_cache_info", "operand_vma"]
 
 # (plan fingerprint, body key, interpret, compiler kwargs) -> lowered call.
 # Kernels rebuild their StreamPlan (and re-partial their body) on every
@@ -81,6 +80,11 @@ def _body_key(body: Callable[..., None]) -> Any:
     return (fn, args, kwargs)
 
 
+def operand_vma(*operands: Any) -> frozenset[str]:
+    """Mesh axes any operand varies over (empty outside ``jax.shard_map``)."""
+    return frozenset().union(*(jax.typeof(x).vma for x in operands))
+
+
 def lower_cache_clear() -> None:
     _LOWER_CACHE.clear()
     _CACHE_STATS.update(hits=0, misses=0, uncacheable=0)
@@ -95,6 +99,7 @@ def lower(
     body: Callable[..., None],
     *,
     interpret: bool = False,
+    vma: frozenset[str] = frozenset(),
     **compiler_kwargs: Any,
 ) -> Callable[..., Any]:
     """Emit the ``pl.pallas_call`` for ``plan`` with hyperstep body ``body``.
@@ -104,13 +109,17 @@ def lower(
     callable to apply to the full (external-memory) operands. Plans with a
     single output return a bare array, matching ``pallas_call``.
 
+    ``vma`` names the mesh axes the outputs vary over when the kernel runs
+    inside ``jax.shard_map`` (the union of the operands' ``jax.typeof(x).vma``,
+    see :func:`operand_vma`); empty outside one.
+
     Lowered calls are cached keyed by ``(plan.fingerprint(), body, interpret,
-    compiler kwargs)`` — the fingerprint covers everything this function
+    vma, compiler kwargs)`` — the fingerprint covers everything this function
     reads from the plan — so re-invoking a kernel with the same shapes stops
     re-constructing (and re-tracing) the pallas_call.
     """
     try:
-        key = (plan.fingerprint(), _body_key(body), interpret,
+        key = (plan.fingerprint(), _body_key(body), interpret, vma,
                tuple(sorted(compiler_kwargs.items())))
         if key[1] is None:
             raise TypeError
@@ -126,7 +135,8 @@ def lower(
         _CACHE_STATS["misses"] += 1
     in_specs = [pl.BlockSpec(t.block_shape, t.index_map) for t in plan.inputs]
     out_specs = [pl.BlockSpec(t.block_shape, t.index_map) for t in plan.outputs]
-    out_shapes = [jax.ShapeDtypeStruct(t.full_shape, t.dtype) for t in plan.outputs]
+    out_shapes = [jax.ShapeDtypeStruct(t.full_shape, t.dtype, vma=vma)
+                  for t in plan.outputs]
     if len(plan.outputs) == 1:
         out_specs, out_shapes = out_specs[0], out_shapes[0]
     call = pl.pallas_call(
@@ -136,7 +146,7 @@ def lower(
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=[pltpu.VMEM(s.shape, s.dtype) for s in plan.scratch],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=plan.dimension_semantics or None,
             **compiler_kwargs,
         ),

@@ -14,8 +14,8 @@ In the plan (:func:`ssm_plan`) A and D have *constant* index maps: they are
 resident operands, fetched once at hyperstep 0 — the fetch schedule charges
 them nothing afterwards, unlike the four per-chunk streams.
 
-Grid: (batch, n_chunks), chunks sequential (state carries across grid steps,
-reset at chunk 0 of each batch element).
+Grid: (batch, channel slices, n_chunks), chunks sequential (state carries
+across grid steps, reset at chunk 0 of each batch element and slice).
 """
 
 from __future__ import annotations
@@ -32,30 +32,63 @@ from repro.kernels import pipeline
 __all__ = ["ssm_scan", "ssm_plan"]
 
 
+def _slab_rows(dtype) -> int:
+    """Rows of one sublane tile: 8 for 32-bit streams, 16 for bf16."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_ref,
-                 *, chunk: int):
-    ci = pl.program_id(1)
+                 *, chunk: int, slab: int):
+    """One chunk of the scan, in slabs of ``slab`` positions.
+
+    Every ref access is tile-aligned: x, Δ and y move one whole
+    ``(slab, d_slice)`` row group at a time, B and C arrive transposed as
+    ``(d_state, chunk)`` and each position's column is picked with a lane
+    mask. The state is kept as ``(d_state, d_slice)`` so its lanes are dense
+    (``d_slice``: the grid's channel slice, see :func:`ssm_plan`).
+    """
+    ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _reset():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[...].astype(jnp.float32)               # (d_inner, d_state)
-    d_skip = d_ref[...].astype(jnp.float32)          # (1, d_inner)
+    a = a_ref[...].astype(jnp.float32)               # (d_state, d_slice)
+    d_skip = d_ref[...].astype(jnp.float32)          # (1, d_slice)
+    b_all = b_ref[0].astype(jnp.float32)             # (d_state, chunk)
+    c_all = c_ref[0].astype(jnp.float32)             # (d_state, chunk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, b_all.shape, 1)
 
-    def step(t, carry):
-        h = carry                                    # (d_inner, d_state)
-        x_t = x_ref[0, t].astype(jnp.float32)        # (d_inner,)
-        dt_t = dt_ref[0, t].astype(jnp.float32)      # (d_inner,)
-        b_t = b_ref[0, t].astype(jnp.float32)        # (d_state,)
-        c_t = c_ref[0, t].astype(jnp.float32)        # (d_state,)
-        da = jnp.exp(dt_t[:, None] * a)              # (d_inner, d_state)
-        h = da * h + (dt_t * x_t)[:, None] * b_t[None, :]
-        y_t = h @ c_t + d_skip[0] * x_t              # (d_inner,)
-        y_ref[0, t] = y_t.astype(y_ref.dtype)
-        return h
+    def column(m, t):                                # (d_state, 1)
+        return jnp.sum(jnp.where(lane == t, m, 0.0), axis=1, keepdims=True)
 
-    h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
+    def step_slab(si, carry):
+        row0 = pl.multiple_of(si * slab, slab)
+        xs = x_ref[0, pl.ds(row0, slab), :].astype(jnp.float32)
+        dts = dt_ref[0, pl.ds(row0, slab), :].astype(jnp.float32)
+        h = h_ref[...]                               # (d_state, d_slice)
+        ys = []
+        for r in range(slab):
+            x_t, dt_t = xs[r:r + 1], dts[r:r + 1]    # (1, d_slice)
+            h = (jnp.exp(dt_t * a) * h
+                 + column(b_all, row0 + r) * (dt_t * x_t))
+            ys.append(jnp.sum(h * column(c_all, row0 + r), axis=0,
+                              keepdims=True) + d_skip * x_t)
+        h_ref[...] = h
+        y_ref[0, pl.ds(row0, slab), :] = (
+            jnp.concatenate(ys, axis=0).astype(y_ref.dtype))
+        return carry
+
+    jax.lax.fori_loop(0, chunk // slab, step_slab, 0)
+
+
+#: Channels per grid slice: the state and each streamed block fit VMEM at
+#: jamba's 8192 channels.
+_BLOCK_D = 512
+
+
+def _channel_block(d_inner: int) -> int:
+    return _BLOCK_D if d_inner % _BLOCK_D == 0 else d_inner
 
 
 def ssm_plan(
@@ -65,6 +98,10 @@ def ssm_plan(
 ) -> StreamPlan:
     """StreamPlan for the chunked selective scan on a padded sequence.
 
+    Channels are independent in the recurrence, so the grid also splits
+    ``d_inner`` into :data:`_BLOCK_D`-wide slices (the whole width when
+    that does not divide it).
+
     ~10·d_inner·d_state FLOPs per scanned position (exp/decay, state update,
     output contraction — same accounting as ``launch.dryrun``'s analytic scan
     correction), times ``chunk`` positions per hyperstep. ``param_dtype``
@@ -73,33 +110,36 @@ def ssm_plan(
     """
     if seq % chunk:
         raise ValueError(f"seq {seq} must be padded to chunk {chunk}")
+    bd = _channel_block(d_inner)
     return StreamPlan(
-        name=f"ssm_b{bsz}_{seq}x{d_inner}x{d_state}_c{chunk}",
-        grid=(bsz, seq // chunk),
+        name=f"ssm_b{bsz}_{seq}x{d_inner}x{d_state}_c{chunk}_d{bd}",
+        grid=(bsz, d_inner // bd, seq // chunk),
         inputs=(
-            TokenSpec("x", (1, chunk, d_inner), lambda i, j: (i, j, 0),
+            TokenSpec("x", (1, chunk, bd), lambda i, k, j: (i, j, k),
                       dtype=dtype, full_shape=(bsz, seq, d_inner)),
-            TokenSpec("dt", (1, chunk, d_inner), lambda i, j: (i, j, 0),
+            TokenSpec("dt", (1, chunk, bd), lambda i, k, j: (i, j, k),
                       dtype=dtype, full_shape=(bsz, seq, d_inner)),
-            TokenSpec("B", (1, chunk, d_state), lambda i, j: (i, j, 0),
-                      dtype=dtype, full_shape=(bsz, seq, d_state)),
-            TokenSpec("C", (1, chunk, d_state), lambda i, j: (i, j, 0),
-                      dtype=dtype, full_shape=(bsz, seq, d_state)),
-            # A and D are resident operands: rate 0 (fetched once, hyperstep
-            # 0, single-buffered — no prefetch buffer reserved for them)
-            TokenSpec("A", (d_inner, d_state), lambda i, j: (0, 0),
-                      dtype=param_dtype, full_shape=(d_inner, d_state), rate=0),
-            TokenSpec("D", (1, d_inner), lambda i, j: (0, 0),
+            # B and C stream transposed, (d_state, seq), so a chunk is a
+            # lane-dense (d_state, chunk) block
+            TokenSpec("B", (1, d_state, chunk), lambda i, k, j: (i, 0, j),
+                      dtype=dtype, full_shape=(bsz, d_state, seq)),
+            TokenSpec("C", (1, d_state, chunk), lambda i, k, j: (i, 0, j),
+                      dtype=dtype, full_shape=(bsz, d_state, seq)),
+            # A and D are resident per channel slice: rate 0 (fetched when
+            # the slice changes, single-buffered — no prefetch buffer)
+            TokenSpec("A", (d_state, bd), lambda i, k, j: (0, k),
+                      dtype=param_dtype, full_shape=(d_state, d_inner), rate=0),
+            TokenSpec("D", (1, bd), lambda i, k, j: (0, k),
                       dtype=param_dtype, full_shape=(1, d_inner), rate=0),
         ),
         outputs=(
             # each finished y chunk streams up as the cursor moves to the next
-            TokenSpec("y", (1, chunk, d_inner), lambda i, j: (i, j, 0),
+            TokenSpec("y", (1, chunk, bd), lambda i, k, j: (i, j, k),
                       dtype=dtype, full_shape=(bsz, seq, d_inner), direction="up"),
         ),
-        scratch=(ScratchSpec("h", (d_inner, d_state), jnp.float32),),
-        dimension_semantics=("arbitrary", "arbitrary"),
-        flops_per_hyperstep=10.0 * chunk * d_inner * d_state,
+        scratch=(ScratchSpec("h", (d_state, bd), jnp.float32),),
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        flops_per_hyperstep=10.0 * chunk * bd * d_state,
     )
 
 
@@ -115,10 +155,16 @@ def ssm_scan(
     chunk: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """Selective scan over the sequence stream; returns y: (B, L, d_inner)."""
+    """Selective scan over the sequence stream; returns y: (B, L, d_inner).
+
+    The chunk is rounded up to whole slabs (:func:`_slab_rows`) and never
+    exceeds the slab-padded sequence; the sequence is zero-padded to whole
+    chunks.
+    """
     bsz, seq, d_inner = x.shape
     d_state = a.shape[1]
-    ck = min(chunk, seq)
+    slab = _slab_rows(x.dtype)
+    ck = min(-(-chunk // slab), -(-seq // slab)) * slab
     pad = (-seq) % ck
     if pad:
         x, dt = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (x, dt))
@@ -129,9 +175,10 @@ def ssm_scan(
                     param_dtype=a.dtype)
     out = pipeline.lower(
         plan,
-        functools.partial(_scan_kernel, chunk=ck),
+        functools.partial(_scan_kernel, chunk=ck, slab=slab),
         interpret=interpret,
-    )(x, dt, b, c, a, d.reshape(1, d_inner))
+        vma=pipeline.operand_vma(x, dt, b, c, a, d),
+    )(x, dt, b.swapaxes(1, 2), c.swapaxes(1, 2), a.T, d.reshape(1, d_inner))
     if pad:
         out = out[:, :seq, :]
     return out

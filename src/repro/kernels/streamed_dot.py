@@ -29,44 +29,56 @@ from repro.kernels import pipeline
 __all__ = ["streamed_dot", "dot_plan"]
 
 
+#: One vreg of f32 partial sums, reduced to α on the final hyperstep.
+_ACC = (8, 128)
+#: Token sizes are whole bf16 tiles (16 × 128 words), so every block is
+#: tile-aligned for 32- and 16-bit streams alike.
+_TOKEN_ALIGN = 16 * 128
+
+
 def _dot_kernel(v_ref, u_ref, out_ref, acc_ref, *, n_tok: int):
     t = pl.program_id(0)
 
     @pl.when(t == 0)
     def _init():
-        acc_ref[0, 0] = jnp.float32(0.0)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    v = v_ref[...].astype(jnp.float32)
-    u = u_ref[...].astype(jnp.float32)
-    acc_ref[0, 0] += jnp.sum(v * u)
+    prod = v_ref[...].astype(jnp.float32) * u_ref[...].astype(jnp.float32)
+    acc_ref[...] += prod.reshape(-1, *_ACC).sum(axis=0)
 
     @pl.when(t == n_tok - 1)
     def _store():
-        out_ref[0, 0] = acc_ref[0, 0]
+        out_ref[...] = jnp.sum(acc_ref[...], keepdims=True)
 
 
 def dot_plan(n_tok: int, c: int, *, dtype=jnp.float32) -> StreamPlan:
     """StreamPlan for α = v·u over ``n_tok`` hypersteps of C-word tokens.
 
-    The backing arrays are viewed as (n_tok, C) token matrices (TPU wants
-    >= 2-D blocks); the (1, 1) output is written once on the final hyperstep.
+    The backing arrays are viewed as lane-dense ``(n_tok·C/128, 128)``
+    matrices, one ``(C/128, 128)`` block per token (C a multiple of 128).
+    The partial sums are one ``(8, 128)`` f32 tile; the (1, 1) α is written
+    up once, on the final hyperstep.
     """
+    if c % 128:
+        raise ValueError(f"token size {c} must be a multiple of 128 words")
+    rows = c // 128
     return StreamPlan(
         name=f"dot_{n_tok}x{c}",
         grid=(n_tok,),
         inputs=(
-            TokenSpec("v", (1, c), lambda t: (t, 0), dtype=dtype,
-                      full_shape=(n_tok, c)),
-            TokenSpec("u", (1, c), lambda t: (t, 0), dtype=dtype,
-                      full_shape=(n_tok, c)),
+            TokenSpec("v", (rows, 128), lambda t: (t, 0), dtype=dtype,
+                      full_shape=(n_tok * rows, 128)),
+            TokenSpec("u", (rows, 128), lambda t: (t, 0), dtype=dtype,
+                      full_shape=(n_tok * rows, 128)),
         ),
         outputs=(
-            # α is written up exactly once, on the final hyperstep: constant
-            # map + rate 0 (write-once result, no revolving output buffer)
+            # α is written up exactly once, on the final hyperstep:
+            # constant map + rate 0 (write-once result, no revolving
+            # output buffer)
             TokenSpec("alpha", (1, 1), lambda t: (0, 0), dtype=jnp.float32,
                       full_shape=(1, 1), direction="up", rate=0),
         ),
-        scratch=(ScratchSpec("acc", (1, 1), jnp.float32),),
+        scratch=(ScratchSpec("acc", _ACC, jnp.float32),),
         dimension_semantics=("arbitrary",),
         flops_per_hyperstep=2.0 * c,
     )
@@ -80,11 +92,16 @@ def streamed_dot(
     token_size: int = 8 * 1024,
     interpret: bool = False,
 ) -> jax.Array:
-    """α = v·u for 1-D vectors streamed token-by-token. Returns a scalar f32."""
+    """α = v·u for 1-D vectors streamed token-by-token. Returns a scalar f32.
+
+    The token size is rounded up to whole :data:`_TOKEN_ALIGN` words and
+    never exceeds the aligned vector; the vectors are zero-padded to whole
+    tokens.
+    """
     if v.shape != u.shape or v.ndim != 1:
         raise ValueError(f"need equal 1-D shapes, got {v.shape}, {u.shape}")
     n = v.shape[0]
-    c = min(token_size, n)
+    c = min(-(-token_size // _TOKEN_ALIGN), -(-n // _TOKEN_ALIGN)) * _TOKEN_ALIGN
     pad = (-n) % c
     if pad:
         v = jnp.pad(v, (0, pad))
@@ -95,5 +112,6 @@ def streamed_dot(
         plan,
         functools.partial(_dot_kernel, n_tok=n_tok),
         interpret=interpret,
-    )(v.reshape(n_tok, c), u.reshape(n_tok, c))
+        vma=pipeline.operand_vma(v, u),
+    )(v.reshape(-1, 128), u.reshape(-1, 128))
     return out[0, 0]

@@ -165,6 +165,7 @@ def streamed_matmul(
         plan,
         functools.partial(_matmul_kernel, n_k=plan.grid[2]),
         interpret=interpret,
+        vma=pipeline.operand_vma(a, b),
     )(a, b)
     if pad_m or pad_n:
         out = out[:m, :n]

@@ -362,12 +362,14 @@ def run_cell(
             hlo_flops=corr["flops"], hlo_bytes=corr["bytes"],
             coll_bytes=corr["coll_bytes"], coll_stats=None,
             model_flops_global=mf, peak_device_bytes=full["peak_bytes"],
+            hw=rf.TPU_V5E,  # the production mesh is a v5e pod
         )
         fused = rf.RooflineReport(
             name=f"{arch}/{shape_name}", chips=chips,
             hlo_flops=corr["flops"], hlo_bytes=corr["bytes_fused"],
             coll_bytes=corr["coll_bytes"], coll_stats=None,
             model_flops_global=mf, peak_device_bytes=full["peak_bytes"],
+            hw=rf.TPU_V5E,  # the production mesh is a v5e pod
         )
         row = report.row()
         row["memory_fused_s"] = fused.memory_seconds

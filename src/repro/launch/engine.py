@@ -36,6 +36,7 @@ the compiled program and harvested at the segment boundary.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Any
@@ -232,7 +233,7 @@ class PagedKVPool:
                                       self.cache["len"], 0)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_lane(pool: dict[str, Any], req: dict[str, Any],
                   lane: jax.Array) -> dict[str, Any]:
     layers = jax.tree_util.tree_map(
@@ -381,14 +382,16 @@ class ServeEngine:
         # verify=True statically checks each segment before dispatch
         # (DESIGN.md §9: lane-aliased up-streams, cursor overruns); results
         # are memoized per cursor state, so steady-state segments — which
-        # rewind the same lane cursors — pay one set lookup, not a re-walk
+        # rewind the same lane cursors — pay one set lookup, not a re-walk.
+        # The weights are the runner's read-only operands: the segment
+        # program donates and returns only (logits, cache, keys, active).
         self._runner = HyperstepRunner(
             self._make_step(), [], out_streams=self.lane_streams,
             machine=self.machine, verify=verify, faults=faults,
             health=self.health,
             calibstore=self.calibstore if self.calibstore is not None
             else False)
-        self._runner.compile(self.segment_len, donate=False)
+        self._runner.compile(self.segment_len)
 
         # Eq. 1 bookkeeping for the admission plans
         cache_bytes = sum(
@@ -407,8 +410,8 @@ class ServeEngine:
         temperature = self.temperature
         lanes = self.max_lanes
 
-        def step(state, _tokens):
-            params, logits, cache, keys, active = state
+        def step(state, _tokens, params):
+            logits, cache, keys, active = state
             if temperature > 0:
                 split = jax.vmap(jax.random.split)(keys)   # (L, 2, 2)
                 keys, subs = split[:, 0], split[:, 1]
@@ -422,10 +425,23 @@ class ServeEngine:
             logits, cache = serve_step(params, cache, {"tokens": tok[:, None]})
             # carry dtype is pinned to f32 (bf16 models would change the scan
             # carry structure mid-trace); argmax is unchanged by the upcast
-            state = (params, logits.astype(jnp.float32), cache, keys, active)
+            state = (logits.astype(jnp.float32), cache, keys, active)
             return state, [tok[i] for i in range(lanes)]
 
         return step
+
+    @property
+    def lane_logits(self) -> jax.Array:
+        """``(max_lanes, 1, vocab)`` f32 logits each lane samples from next."""
+        return self._logits
+
+    def segment_memory(self) -> Any:
+        """``memory_analysis()`` of the compiled segment program."""
+        state = (self._logits, self.pool.cache, self._keys,
+                 jnp.asarray(self._active))
+        return self._runner.lower(state, self.segment_len,
+                                  operands=self.params).compile(
+                                  ).memory_analysis()
 
     # -- admission ------------------------------------------------------------
 
@@ -654,7 +670,8 @@ class ServeEngine:
         """
         for attempt in range(self._dispatch_retries + 1):
             try:
-                return self._runner.run(state, self.segment_len, compiled=True)
+                return self._runner.run(state, self.segment_len, compiled=True,
+                                        operands=self.params)
             except FaultInjected as e:
                 self.health.emit(
                     "BSPS204", f"segment {self._segments_run} dispatch failed "
@@ -783,10 +800,10 @@ class ServeEngine:
 
         self._runner.plan = self._decode_plan(occupancy)
         self._runner.reset_records()
-        state = (self.params, self._logits, self.pool.cache, self._keys,
+        state = (self._logits, self.pool.cache, self._keys,
                  jnp.asarray(self._active))
         state = self._dispatch_segment(state)
-        _, self._logits, cache, self._keys, _ = state
+        self._logits, cache, self._keys, _ = state
         self.pool.cache = dict(cache)
         wall = self._runner.records[-1].step_seconds
         row = self._runner.predicted_vs_measured()

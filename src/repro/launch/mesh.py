@@ -10,13 +10,25 @@ gradient-compression hooks target that axis).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *,
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: GSPMD propagates shardings.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which every gather
+    and every jit outside ``jax.set_mesh`` must name its output sharding; the
+    sharded code here is written for propagation.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int | None = None) -> Mesh:
@@ -36,7 +48,7 @@ def make_host_mesh(model: int | None = None) -> Mesh:
         raise ValueError(
             f"model={model} does not divide the {n} available device(s); "
             f"a ({n // model}, {model}) mesh would drop {n % model} of them")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_host_core_mesh(hosts: int, *, model: int | None = None) -> Mesh:
@@ -70,5 +82,5 @@ def make_host_core_mesh(hosts: int, *, model: int | None = None) -> Mesh:
         raise ValueError(
             f"model={model} does not divide the {per_host} device(s) per host; "
             f"would drop {per_host % model} of them")
-    return jax.make_mesh((hosts, per_host // model, model),
-                         ("host", "data", "model"))
+    return auto_mesh((hosts, per_host // model, model),
+                     ("host", "data", "model"))

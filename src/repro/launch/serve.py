@@ -42,6 +42,7 @@ from repro.core.calibrate import default_machine
 from repro.core.hyperstep import HyperstepRecord, HyperstepRunner
 from repro.core.plan import ScratchSpec, StreamPlan, autotune, host_plan, streamed_operand
 from repro.core.stream import StreamSet
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.registry import Registry
 from repro.models import model as M
 from repro.train.steps import make_serve_step
@@ -214,9 +215,10 @@ def _build_decode_runner(cfg, temperature: float, batch: int, max_len: int,
     The runner's compiled program scans all ``steps`` decode hypersteps in a
     single dispatch; caching the runner caches the traced program, so
     repeated ``generate()`` calls with the same shape re-dispatch without
-    re-tracing. Params ride in the scan carry (a new jit argument each call —
-    weight updates need no recompile) and are *not* donated: the caller keeps
-    owning them across requests. The runner and its ``generated`` backing
+    re-tracing. Params are the runner's read-only operands (a new jit
+    argument each call — weight updates need no recompile), neither carried
+    nor donated: the caller keeps owning them across requests, and the
+    program holds one copy. The runner and its ``generated`` backing
     stream are shared mutable state; the registry entry's lock serialises
     concurrent same-shape requests.
     """
@@ -225,15 +227,15 @@ def _build_decode_runner(cfg, temperature: float, batch: int, max_len: int,
     generated = streams.create(np.zeros((steps, batch), np.int32), 1,
                                name="generated")
 
-    def hyperstep(state, _tokens):
-        params, logits, cache, key = state
+    def hyperstep(state, _tokens, params):
+        logits, cache, key = state
         tok, logits, cache, key = decode_fn(params, logits, cache, key)
-        return (params, logits, cache, key), [tok[:, 0]]
+        return (logits, cache, key), [tok[:, 0]]
 
     runner = HyperstepRunner(
         hyperstep, [], out_streams=[generated],
         plan=_decode_plan(cfg, batch, max_len, generated))
-    runner.compile(steps, donate=False)
+    runner.compile(steps)
     return runner, generated
 
 
@@ -296,7 +298,8 @@ def generate(
             with entry.lock:            # cached runner + stream are shared
                 runner.machine = machine
                 runner.reset_records()  # per-request row, program stays cached
-                runner.run((params, logits, cache, key), compiled=True)
+                runner.run((logits, cache, key), compiled=True,
+                           operands=params)
                 decode_seconds = [runner.records[-1].step_seconds]
                 generated_ids = np.array(generated.data, np.int32)
                 records = list(runner.records)
@@ -347,6 +350,7 @@ def main() -> None:
                     help="instrumented per-token decode loop instead of the "
                          "compiled single-dispatch scan")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     params = M.init_params(cfg, jax.random.PRNGKey(0))

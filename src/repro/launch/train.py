@@ -2,8 +2,8 @@
 
 Runs the end-to-end training loop (data pipeline → jitted hyperstep →
 checkpoint/restart) on the local devices. ``--smoke`` selects the reduced
-same-family config (CPU-runnable); the full configs are exercised through the
-dry-run (``repro.launch.dryrun``) since this container has no TPU.
+same-family config (CPU-runnable); the published configs need an accelerator
+(``chip_smoke.py`` runs one on a TPU) or the dry-run (``repro.launch.dryrun``).
 """
 
 from __future__ import annotations
@@ -14,6 +14,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamW
 from repro.optim.schedule import linear_warmup_cosine, wsd
 from repro.train.loop import TrainConfig, train
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     # minicpm's distinctive recipe is WSD; everything else gets cosine

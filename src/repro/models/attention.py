@@ -4,7 +4,8 @@ Three interchangeable inner implementations, all BSPS streamings of the KV
 sequence (DESIGN.md: attention *is* a pseudo-streaming algorithm — resident Q
 token, KV stream, online-softmax state):
 
-* ``kernel``    — the Pallas flash kernel (TPU runtime path);
+* ``kernel``    — the Pallas flash kernel (one-chip TPU runtime path; its
+                  backward is the custom-VJP flash below);
 * ``blockwise`` — pure-JAX online softmax, KV stream chunks via ``lax.scan``
                   (portable lowering used by the multi-pod dry-run; linear
                   memory in sequence length);
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import os
 
 from repro.configs.base import ModelConfig
+from repro.distributed import ctx
 from repro.kernels import ops, ref
 from repro.models.flash import flash_attention_vjp
 from repro.models.layers import _dense_init, apply_rope
@@ -190,7 +192,7 @@ def attention_core(
     """(B, S, H, D)-layout wrapper choosing the inner implementation."""
     qt, kt, vt = (t.swapaxes(1, 2) for t in (q, k, v))  # -> (B, H, S, D)
     if impl == "auto":
-        if jax.default_backend() == "tpu" and not ops.use_ref():
+        if jax.default_backend() == "tpu" and not ctx.sharded():
             impl = "kernel"
         else:
             # portable path: flash (custom-vjp) is the shipped default after

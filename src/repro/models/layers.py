@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.distributed import ctx
 from repro.kernels import ops
 
 Params = dict[str, Any]
@@ -144,9 +145,10 @@ def apply_mlp(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
 
 
 def ops_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Batched (..., d) @ (d, f). Routes through the BSPS Pallas kernel on TPU;
-    on other backends XLA's dot keeps dry-run lowering portable."""
-    if jax.default_backend() == "tpu" and not ops.use_ref():
+    """Batched (..., d) @ (d, f). Routes through the BSPS Pallas kernel on one
+    TPU; on other backends, and under a sharded mesh (GSPMD cannot partition a
+    Pallas kernel), XLA's dot."""
+    if jax.default_backend() == "tpu" and not ctx.sharded():
         lead = x.shape[:-1]
         out = ops.matmul(x.reshape(-1, x.shape[-1]), w, out_dtype=x.dtype)
         return out.reshape(*lead, w.shape[-1])
@@ -156,11 +158,15 @@ def ops_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
 # -- embeddings ----------------------------------------------------------------
 
 
+#: Standard deviation of the token-embedding init (also the tied lm head).
+EMBED_INIT_STD = 0.02
+
+
 def init_embedding(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     ks = jax.random.split(key, 2)
     v = cfg.padded_vocab
     p = {"tokens": (jax.random.normal(ks[0], (v, cfg.d_model), jnp.float32)
-                    * 0.02).astype(dtype)}
+                    * EMBED_INIT_STD).astype(dtype)}
     if not cfg.tie_embeddings:
         p["head"] = _dense_init(ks[1], (cfg.d_model, v), dtype)
     return p

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.distributed import ctx
 from repro.kernels import ops, ref
 from repro.models.layers import _dense_init
 
@@ -147,7 +148,8 @@ def mamba_forward(
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
 
     if impl == "auto":
-        impl = "kernel" if (jax.default_backend() == "tpu" and not ops.use_ref()) else "chunked"
+        impl = ("kernel" if jax.default_backend() == "tpu" and not ctx.sharded()
+                else "chunked")
     if impl == "kernel":
         y = ops.selective_scan(xin, dt.astype(xin.dtype), bmat, cmat, a,
                                p["d_skip"].astype(jnp.float32))

@@ -22,6 +22,7 @@ from repro.core import (
 )
 from repro.core.hlo import collective_bytes, parse_shape_bytes
 from repro.core.stream import StreamBusyError, StreamClosedError
+from repro.launch.mesh import auto_mesh
 
 
 # ------------------------------------------------------------- machines ----
@@ -42,6 +43,17 @@ def test_v5e_chip_is_bandwidth_rich_vs_parallella():
     # e(v5e) ≈ 481 flop/word; still bandwidth-heavy for O(1)-intensity kernels
     assert 400 < TPU_V5E_CHIP.e < 600
     assert TPU_V5E_CHIP.balance > 1  # inner product is bandwidth heavy (e > 1)
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """The kind JAX reports for a v5e chip maps to its published peaks; an
+    unlisted kind is refused rather than priced as a v5e."""
+    from repro.core import hardware_spec
+
+    hw = hardware_spec("TPU v5 lite")
+    assert (hw.peak_flops, hw.hbm_bandwidth, hw.hbm_bytes) == (197e12, 819e9, 16e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        hardware_spec("cpu")
 
 
 # ---------------------------------------------------------------- streams ----
@@ -181,15 +193,13 @@ def test_collective_bytes_on_real_hlo():
     devs = jax.devices()
     if len(devs) < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = auto_mesh((1,), ("x",))
     from jax.sharding import PartitionSpec as P
-
-    from repro.compat import shard_map
 
     def f(a):
         return jax.lax.psum(a, "x")
 
-    g = shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
+    g = jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
     txt = jax.jit(g).lower(jnp.ones((8, 8))).compile().as_text()
     stats = collective_bytes(txt)
     # single-device: collective may be elided; parser must not crash and

@@ -137,8 +137,9 @@ def test_dp_size_registers():
 def test_cannon_matmul_matches_xla():
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from repro.distributed.cannon import cannon_matmul
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = auto_mesh((2, 2), ("data", "model"))
         rng = np.random.default_rng(0)
         for (m, k, n) in [(64, 32, 48), (8, 8, 8), (128, 64, 64)]:
             a = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
@@ -155,9 +156,10 @@ def test_cannon_collective_traffic_is_block_sized():
     redundancy), visible as collective-permutes of exactly block size."""
     out = _run_sub("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import auto_mesh
         from repro.distributed.cannon import cannon_matmul
         from repro.core.hlo import collective_bytes
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = auto_mesh((2, 2), ("data", "model"))
         a = jnp.ones((64, 64), jnp.float32)
         b = jnp.ones((64, 64), jnp.float32)
         txt = jax.jit(lambda a, b: cannon_matmul(a, b, mesh=mesh)
@@ -178,7 +180,8 @@ def test_two_level_cannon_plan_driven_on_4_devices():
         import jax, numpy as np
         from repro.core import EPIPHANY_III, cannon_bsps_cost
         from repro.distributed.cannon import two_level_cannon
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 2), ("data", "model"))
         rng = np.random.default_rng(0)
         n, m_blocks, n_grid = 64, 2, 2
         a = rng.standard_normal((n, n)).astype(np.float32)
@@ -226,6 +229,7 @@ def test_gspmd_train_step_runs_on_4_devices():
     the production dry-run, actually executed."""
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np, dataclasses
+        from repro.launch.mesh import auto_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.distributed import sharding as sh, ctx
@@ -236,7 +240,7 @@ def test_gspmd_train_step_runs_on_4_devices():
 
         cfg = dataclasses.replace(get_config("qwen2-moe-a2.7b", smoke=True),
                                   scan_layers=True, remat="full")
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = auto_mesh((2, 2), ("data", "model"))
         with mesh, ctx.mesh_axes(dict(mesh.shape)):
             params = M.init_params(cfg, jax.random.PRNGKey(0))
             specs = sh.param_specs(cfg, mesh, params)
@@ -260,8 +264,9 @@ def test_pipeline_parallel_matches_sequential():
     """GPipe fill–drain over a 4-stage ring == sequential stage application."""
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from repro.distributed.pipeline import pipeline_apply
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = auto_mesh((4,), ("model",))
         rng = np.random.default_rng(0)
         S, M, B, D = 4, 6, 2, 8
         ws = jnp.asarray(rng.standard_normal((S, D, D)) * 0.3, jnp.float32)

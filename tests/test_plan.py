@@ -131,9 +131,10 @@ def test_autotune_prefers_cheapest_feasible():
     # dot product, bandwidth heavy (e=4): Eq. 1 says bigger tokens are
     # cheaper (one fewer overlapped fetch per doubling), so the planner
     # should pick the largest token that fits local memory — the paper's
-    # "size tokens as large as local memory allows".
+    # "size tokens as large as local memory allows". The budget holds the
+    # double-buffered tokens plus the kernel's one-vreg (4 KiB) accumulator.
     budget = BSPAccelerator(p=1, g=0.0, l=0.0, r=1e9, e=4.0,
-                            L=1500, E=1 << 30, word_bytes=4)
+                            L=2500, E=1 << 30, word_bytes=4)
     n = 4096
 
     def build(token_size):

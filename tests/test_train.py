@@ -217,15 +217,16 @@ def test_elastic_restore_onto_different_mesh(tmp_path):
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import auto_mesh
         from repro.train import checkpoint as ck
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = auto_mesh((4,), ("data",))
         w = jax.device_put(jnp.arange(32, dtype=jnp.float32).reshape(8, 4),
                            NamedSharding(mesh, P("data", None)))
         ck.save(%r, 1, {"params": {"w": w}}, blocking=True)
 
         # 'new job' on a 2x2 mesh with a different sharding
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh2 = auto_mesh((2, 2), ("data", "model"))
         def sharder(group, tree):
             return jax.tree_util.tree_map(
                 lambda t: jax.device_put(jnp.asarray(t),
