@@ -96,12 +96,15 @@ def _run_rate(cfg, params, acc, rate: float, smoke: bool) -> dict:
                       retry_backoff_s=0.0)
     prompts = _prompts(n_req, cfg.vocab_size)
     _drain_wave(eng, prompts, steps)        # warm: trace + compile
-    tok0 = len(eng.token_latencies)
+    seg0 = len(eng.segment_log)
     tps_runs = []
     for _ in range(2 if smoke else 3):
         toks, wall = _drain_wave(eng, prompts, steps)
         tps_runs.append(toks / max(wall, 1e-12))
-    lat = np.asarray(eng.token_latencies[tok0:])
+    # each token takes its segment's wall over the segment length
+    segs = eng.segment_log[seg0:]
+    lat = np.repeat([s["wall_seconds"] / eng.segment_len for s in segs],
+                    [s["tokens"] for s in segs])
     want = (1 + (2 if smoke else 3)) * n_req * steps
     drained = (not eng.queue and not eng.running
                and sum(len(r.generated) for r in eng.finished.values())

@@ -104,7 +104,7 @@ def _case_batch_sweep(smoke: bool, acc) -> dict:
         prompts = _prompts(batch, cfg.vocab_size, smoke)
         _drain(eng, prompts, steps)          # warm: trace + compile the program
         tps_runs = []
-        tok0 = len(eng.token_latencies)
+        seg0 = len(eng.segment_log)
         for _ in range(3):
             toks, wall = _drain(eng, prompts, steps)
             tps_runs.append(toks / max(wall, 1e-12))
@@ -118,7 +118,10 @@ def _case_batch_sweep(smoke: bool, acc) -> dict:
             "health": stats["health"],
         }
         if batch == FLOOR_BATCH:
-            lat = np.asarray(eng.token_latencies[tok0:])
+            # each token takes its segment's wall over the segment length
+            segs = eng.segment_log[seg0:]
+            lat = np.repeat([s["wall_seconds"] / eng.segment_len for s in segs],
+                            [s["tokens"] for s in segs])
             latency = {"p50_s": float(np.percentile(lat, 50)),
                        "p99_s": float(np.percentile(lat, 99))}
         admission_rows += [

@@ -67,10 +67,15 @@ def main() -> None:
           f"({pages.free_pages} free at drain)")
 
     stats = eng.stats()
+    segs = eng.segment_log
+    decode_s = sum(s["wall_seconds"] for s in segs)
+    # each token takes its segment's wall over the segment length
+    per_token = np.repeat([s["wall_seconds"] / eng.segment_len for s in segs],
+                          [s["tokens"] for s in segs])
     print(f"\n{stats['requests']} requests, {stats['tokens']} tokens, "
-          f"{stats['tokens_per_s']:.0f} tok/s decode, "
-          f"p50={stats['latency_p50_s'] * 1e3:.2f}ms "
-          f"p99={stats['latency_p99_s'] * 1e3:.2f}ms per token, "
+          f"{stats['tokens'] / max(decode_s, 1e-12):.0f} tok/s decode, "
+          f"p50={np.percentile(per_token, 50) * 1e3:.2f}ms "
+          f"p99={np.percentile(per_token, 99) * 1e3:.2f}ms per token, "
           f"mean occupancy {stats['mean_occupancy']:.1f}")
     first = min(out)
     print(f"rid {first} tokens: {out[first][:24].tolist()}")

@@ -924,9 +924,19 @@ class HyperstepRunner:
                     for outs in self._out_streams]
         return prog._call.lower(state, out_bufs, stacked, operands)
 
-    def _run_compiled(self, state: Any, num_hypersteps: int | None,
+    def _run_compiled(self, state: Any, total: int,
                       operands: Any = None) -> Any:
-        total = self._resolve_total(num_hypersteps)
+        """One compiled dispatch of ``total`` hypersteps.
+
+        Host spans (``jax.profiler.TraceAnnotation``) name its parts on a
+        profiler trace: ``runtime.compile`` (only on a cache miss or a stale
+        schedule), ``runtime.stage``, ``runtime.scan`` and ``runtime.drain``
+        (exactly the intervals recorded as ``fetch_seconds``,
+        ``step_seconds`` and ``writeback_seconds``), ``runtime.check`` (the
+        health gate over the out buffers) and ``runtime.record`` (health
+        scoring and the calibration record); :meth:`run` wraps the call in
+        ``runtime.dispatch``.
+        """
         if total <= 0:
             return state
         self._verify_or_raise(total)
@@ -947,7 +957,9 @@ class HyperstepRunner:
             # always pass this check and keep the cached program.
             prog = None
         if prog is None:
-            prog = self.compile(total)
+            with jax.profiler.TraceAnnotation("runtime.compile",
+                                              hypersteps=total):
+                prog = self.compile(total)
         sched = prog.schedule
         for core, ins, outs in zip(self._core_ids, self._streams,
                                    self._out_streams):
@@ -956,51 +968,57 @@ class HyperstepRunner:
         try:
             # staging: the whole pseudo-stream crosses the external link once
             # (the compiled twin of the prologue + the per-step prefetches)
-            t0 = time.perf_counter()
-            stacked = [[s.as_stacked() for s in ss] for ss in self._streams]
-            out_bufs = [[s.as_stacked() for s in outs]
-                        for outs in self._out_streams]
-            stacked = _block(stacked)
-            out_bufs = _block(out_bufs)
-            if self.faults is not None:
-                # the whole run stages at once, so every dma_stall trigger in
-                # range lands on this one link crossing
-                d = sum(self.faults.fetch_delay(g)
-                        for g in range(base, base + total))
-                if d:
-                    time.sleep(d)
-            stage_s = time.perf_counter() - t0
+            with jax.profiler.TraceAnnotation("runtime.stage"):
+                t0 = time.perf_counter()
+                stacked = [[s.as_stacked() for s in ss]
+                           for ss in self._streams]
+                out_bufs = [[s.as_stacked() for s in outs]
+                            for outs in self._out_streams]
+                stacked = _block(stacked)
+                out_bufs = _block(out_bufs)
+                if self.faults is not None:
+                    # the whole run stages at once, so every dma_stall
+                    # trigger in range lands on this one link crossing
+                    d = sum(self.faults.fetch_delay(g)
+                            for g in range(base, base + total))
+                    if d:
+                        time.sleep(d)
+                stage_s = time.perf_counter() - t0
 
-            t1 = time.perf_counter()
-            state, out_bufs = prog(state, out_bufs, stacked, operands)
-            state = _block(state)
-            out_bufs = _block(out_bufs)
-            if self.faults is not None:
-                d = sum(self.faults.compute_delay(g)
-                        for g in range(base, base + total))
-                if d:
-                    time.sleep(d)
-            run_s = time.perf_counter() - t1
+            with jax.profiler.TraceAnnotation("runtime.scan"):
+                t1 = time.perf_counter()
+                state, out_bufs = prog(state, out_bufs, stacked, operands)
+                state = _block(state)
+                out_bufs = _block(out_bufs)
+                if self.faults is not None:
+                    d = sum(self.faults.compute_delay(g)
+                            for g in range(base, base + total))
+                    if d:
+                        time.sleep(d)
+                run_s = time.perf_counter() - t1
 
             if self.faults is not None:
                 out_bufs = self._apply_compiled_corruption(
                     sched, out_bufs, base, total)
             if self.health is not None:
-                for c in range(self.num_cores):
-                    for j, buf in enumerate(out_bufs[c]):
-                        self.health.check_output(
-                            buf, source=self._source_name, index=base)
+                with jax.profiler.TraceAnnotation("runtime.check"):
+                    for c in range(self.num_cores):
+                        for j, buf in enumerate(out_bufs[c]):
+                            self.health.check_output(
+                                buf, source=self._source_name, index=base)
 
             # drain the finished output tokens back to external memory and
             # advance the cursors to the walk's final positions (so adapter
             # streams — e.g. a data pipeline — see their tokens consumed)
-            t2 = time.perf_counter()
-            for c, (core, outs) in enumerate(zip(self._core_ids,
-                                                 self._out_streams)):
-                for j, s in enumerate(outs):
-                    s.load_stacked(out_bufs[c][j])
-                    s.seek(core, sched.final_out_cursors[c][j] - s.cursor)
-            drain_s = time.perf_counter() - t2
+            with jax.profiler.TraceAnnotation("runtime.drain"):
+                t2 = time.perf_counter()
+                for c, (core, outs) in enumerate(zip(self._core_ids,
+                                                     self._out_streams)):
+                    for j, s in enumerate(outs):
+                        s.load_stacked(out_bufs[c][j])
+                        s.seek(core,
+                               sched.final_out_cursors[c][j] - s.cursor)
+                drain_s = time.perf_counter() - t2
             for c, (core, ins) in enumerate(zip(self._core_ids, self._streams)):
                 for i, s in enumerate(ins):
                     s.seek(core, sched.final_in_cursors[c][i] - s.cursor)
@@ -1049,10 +1067,11 @@ class HyperstepRunner:
         # so health scoring and the calibration record use the full wall
         # (a stalled DMA lands in stage_s and must move the ratio)
         wall = stage_s + run_s + drain_s
-        self._observe(total, 1, self.lifetime_dispatches - 1,
-                      measured_seconds=wall)
-        self._record_measurement(total, 1, len(self.records) - 1,
-                                 fault_start, wall)
+        with jax.profiler.TraceAnnotation("runtime.record"):
+            self._observe(total, 1, self.lifetime_dispatches - 1,
+                          measured_seconds=wall)
+            self._record_measurement(total, 1, len(self.records) - 1,
+                                     fault_start, wall)
         return state
 
     def run(self, state: Any, num_hypersteps: int | None = None, *,
@@ -1078,7 +1097,11 @@ class HyperstepRunner:
         them through the scan.
         """
         if compiled:
-            return self._run_compiled(state, num_hypersteps, operands)
+            total = self._resolve_total(num_hypersteps)
+            with jax.profiler.TraceAnnotation(
+                    "runtime.dispatch", plan=self._source_name,
+                    hypersteps=total):
+                return self._run_compiled(state, total, operands)
         extra = () if operands is None else (operands,)
         ncores = self.num_cores
         # One background lane per core, like the single DMA engine per

@@ -84,6 +84,8 @@ class Request:
     generated: list[int] = dataclasses.field(default_factory=list)
     prefill_seconds: float = 0.0
     submit_time: float = 0.0
+    # stamped as the join starts, before the prefill: join_time - submit_time
+    # is the request's wait in the queue
     join_time: float | None = None
     done_time: float | None = None
     timed_out: bool = False
@@ -365,7 +367,6 @@ class ServeEngine:
         self.finished: dict[int, Request] = {}
         self.admission_log: list[dict[str, Any]] = []
         self.segment_log: list[dict[str, Any]] = []
-        self.token_latencies: list[float] = []    # seconds/token, every token
         self._next_rid = 0
         self._segments_run = 0
 
@@ -545,50 +546,62 @@ class ServeEngine:
                     "BSPS207", f"page pool exhausted; request {req.rid} "
                     f"deferred (needs {need} positions)", index=req.rid)
                 break                      # page pressure: defer (FCFS)
-            current = self._decode_plan(occupancy) if occupancy else None
-            candidate = self._decode_plan(occupancy + 1,
-                                          extra_len=req.prompt_len)
-            dec = admission_decision(
-                current, candidate, self._admission_machine(),
-                tokens_per_hyperstep=occupancy + 1)
-            self.admission_log.append({
-                "rid": req.rid, "segment": self._segments_run,
-                "occupancy_before": occupancy,
-                "measured_verdict": None,       # filled by the next segment
-                "machine_pack": self._machine_pack_label(),
-                "repriced": False,
-                **dec.row(),
-            })
+            with jax.profiler.TraceAnnotation("engine.admit", rid=req.rid):
+                current = self._decode_plan(occupancy) if occupancy else None
+                candidate = self._decode_plan(occupancy + 1,
+                                              extra_len=req.prompt_len)
+                dec = admission_decision(
+                    current, candidate, self._admission_machine(),
+                    tokens_per_hyperstep=occupancy + 1)
+                self.admission_log.append({
+                    "rid": req.rid, "segment": self._segments_run,
+                    "occupancy_before": occupancy,
+                    "measured_verdict": None,       # filled by the next segment
+                    "machine_pack": self._machine_pack_label(),
+                    "repriced": False,
+                    **dec.row(),
+                })
             if not dec.admit:
                 break                      # bandwidth boundary: defer
             self.queue.popleft()
             self._join(req)
 
     def _join(self, req: Request) -> None:
-        claim = self.pool.try_admit(req.rid, req.prompt_len
-                                    + self._scheduled_steps(req.max_new_tokens))
-        assert claim is not None           # _try_join checked both resources
-        lane, _pages = claim
-        req.lane = lane
-
-        # batch-1 chunked prefill at the pool's geometry, then one scatter
-        # into the lane — the only copy in the request's lifetime
-        block = prefill_block_size(self.cfg, 1, req.prompt_len, self.machine)
-        prefill = make_prefill(self.cfg, block)
-        cache = M.init_cache(self.cfg, 1, self.pool_seq)
-        t0 = time.perf_counter()
-        logits, cache = prefill(self.params, cache,
-                                jnp.asarray(req.prompt[None, :], jnp.int32))
-        jax.block_until_ready(logits)
-        req.prefill_seconds = time.perf_counter() - t0
-
-        self.pool.join(lane, cache)
-        self._logits = self._logits.at[lane].set(
-            logits[0].astype(jnp.float32))
-        self._keys = self._keys.at[lane].set(jax.random.PRNGKey(req.seed))
-        self._active[lane] = True
         req.join_time = time.perf_counter()
-        self.running[req.rid] = req
+        with jax.profiler.TraceAnnotation(
+                "engine.join", rid=req.rid, prompt_len=req.prompt_len,
+                queued_s=req.join_time - req.submit_time):
+            claim = self.pool.try_admit(
+                req.rid, req.prompt_len
+                + self._scheduled_steps(req.max_new_tokens))
+            assert claim is not None       # _try_join checked both resources
+            lane, _pages = claim
+            req.lane = lane
+
+            # batch-1 chunked prefill at the pool's geometry, then one
+            # scatter into the lane — the only copy in the request's lifetime
+            block = prefill_block_size(self.cfg, 1, req.prompt_len,
+                                       self.machine)
+            prefill = make_prefill(self.cfg, block)
+            cache = M.init_cache(self.cfg, 1, self.pool_seq)
+            with jax.profiler.TraceAnnotation(
+                    "engine.prefill", rid=req.rid, prompt_len=req.prompt_len,
+                    block=block):
+                t0 = time.perf_counter()
+                logits, cache = prefill(
+                    self.params, cache,
+                    jnp.asarray(req.prompt[None, :], jnp.int32))
+                jax.block_until_ready(logits)
+                req.prefill_seconds = time.perf_counter() - t0
+
+            with jax.profiler.TraceAnnotation("engine.scatter", rid=req.rid):
+                self.pool.join(lane, cache)
+                self._logits = self._logits.at[lane].set(
+                    logits[0].astype(jnp.float32))
+                self._keys = self._keys.at[lane].set(
+                    jax.random.PRNGKey(req.seed))
+            self._active[lane] = True
+            self.running[req.rid] = req
 
     # -- request lifecycle (retire / cancel / deadlines) ----------------------
 
@@ -727,32 +740,34 @@ class ServeEngine:
         event = self.health.pop_recalibration()
         if event is None:
             return
-        seg = self._segments_run - 1
-        if self.calibstore is None:
+        with jax.profiler.TraceAnnotation("engine.recalibrate"):
+            seg = self._segments_run - 1
+            if self.calibstore is None:
+                self.health.emit(
+                    "BSPS222", "calibration drift detected but recording is "
+                    "disabled; nothing to refit from (ratio "
+                    f"{event.ratio:.3g}x baseline)", index=seg,
+                    value=event.ratio)
+                return
+            band = plan_band(self._runner.plan)
+            refit = self.calibstore.refit_machine(
+                self.machine, band=band, window=self.health.drift_window)
+            if refit is None:
+                self.health.emit(
+                    "BSPS222", f"calibration drift (ratio {event.ratio:.3g}x "
+                    f"baseline) but band {band} is under-evidenced; keeping "
+                    "the closed-form pack", index=seg, value=event.ratio)
+                return
+            self.active_machine = refit
+            self._runner.machine = refit
+            self.health.rebaseline()
             self.health.emit(
-                "BSPS222", "calibration drift detected but recording is "
-                f"disabled; nothing to refit from (ratio {event.ratio:.3g}x "
-                "baseline)", index=seg, value=event.ratio)
-            return
-        band = plan_band(self._runner.plan)
-        refit = self.calibstore.refit_machine(
-            self.machine, band=band, window=self.health.drift_window)
-        if refit is None:
-            self.health.emit(
-                "BSPS222", f"calibration drift (ratio {event.ratio:.3g}x "
-                f"baseline) but band {band} is under-evidenced; keeping the "
-                "closed-form pack", index=seg, value=event.ratio)
-            return
-        self.active_machine = refit
-        self._runner.machine = refit
-        self.health.rebaseline()
-        self.health.emit(
-            "BSPS221", f"adopted calibration-store refit for band {band}: "
-            f"g {self.machine.g:.3g}->{refit.g:.3g}, "
-            f"l {self.machine.l:.3g}->{refit.l:.3g}, "
-            f"e {self.machine.e:.3g}->{refit.e:.3g}; admission re-priced",
-            index=seg, value=refit.e / max(self.machine.e, 1e-12))
-        self._reprice_admission()
+                "BSPS221", f"adopted calibration-store refit for band {band}: "
+                f"g {self.machine.g:.3g}->{refit.g:.3g}, "
+                f"l {self.machine.l:.3g}->{refit.l:.3g}, "
+                f"e {self.machine.e:.3g}->{refit.e:.3g}; admission re-priced",
+                index=seg, value=refit.e / max(self.machine.e, 1e-12))
+            self._reprice_admission()
 
     def _reprice_admission(self) -> None:
         """Log a fresh admission verdict priced on the refit pack.
@@ -791,60 +806,77 @@ class ServeEngine:
         })
 
     def step_segment(self) -> int:
-        """Run one packed segment; returns tokens harvested for real requests."""
-        self._expire_deadlines()
-        self._try_join()
-        occupancy = self._occupancy()
-        if occupancy == 0:
-            return 0
+        """Run one packed segment; returns tokens harvested for real requests.
 
-        self._runner.plan = self._decode_plan(occupancy)
-        self._runner.reset_records()
-        state = (self._logits, self.pool.cache, self._keys,
-                 jnp.asarray(self._active))
-        state = self._dispatch_segment(state)
-        self._logits, cache, self._keys, _ = state
-        self.pool.cache = dict(cache)
-        wall = self._runner.records[-1].step_seconds
-        row = self._runner.predicted_vs_measured()
-        measured = ("bandwidth_heavy" if row["bandwidth_heavy_measured"]
-                    else "compute_bound")
-        for entry in self.admission_log:
-            if entry["measured_verdict"] is None:
-                entry["measured_verdict"] = measured
-        self._segments_run += 1
+        Host spans (``jax.profiler.TraceAnnotation``) name each part of the
+        boundary on a profiler trace: ``engine.segment`` around the call,
+        inside it ``engine.admit`` / ``engine.join`` (its ``engine.prefill``
+        and ``engine.scatter``) per queued head, ``engine.plan``, the
+        runner's ``runtime.dispatch``, ``engine.harvest`` and
+        ``engine.account``. They cost about a microsecond each untraced.
+        """
+        with jax.profiler.TraceAnnotation(
+                "engine.segment", segment=self._segments_run) as span:
+            self._expire_deadlines()
+            self._try_join()
+            occupancy = self._occupancy()
+            span.set_metadata(occupancy=occupancy)
+            if occupancy == 0:
+                return 0
 
-        # harvest each lane's up-stream, retire satisfied requests
-        harvested = 0
-        per_token = wall / self.segment_len
-        for req in list(self.running.values()):
-            data = np.asarray(self.lane_streams[req.lane].data, np.int32)
-            take = min(self.segment_len,
-                       req.max_new_tokens - len(req.generated))
-            # corruption gate: a bit-flipped id is out of vocab range
-            self.health.check_output(
-                data[:take], lo=0, hi=self.cfg.vocab_size,
-                source=f"lane{req.lane}", index=self._segments_run - 1)
-            req.generated.extend(int(t) for t in data[:take])
-            harvested += take
-            self.token_latencies.extend([per_token] * take)
-            if req.done:
-                req.done_time = time.perf_counter()
-                self._retire(req)
-        self.pool.reset_inactive(self._active)
-        self._update_degradation()
-        self._maybe_recalibrate()
-        self._expire_deadlines()
+            with jax.profiler.TraceAnnotation("engine.plan"):
+                self._runner.plan = self._decode_plan(occupancy)
+                self._runner.reset_records()
+                state = (self._logits, self.pool.cache, self._keys,
+                         jnp.asarray(self._active))
+            state = self._dispatch_segment(state)
+            self._logits, cache, self._keys, _ = state
+            self.pool.cache = dict(cache)
+            wall = self._runner.records[-1].step_seconds
+            self._segments_run += 1
 
-        self.segment_log.append({
-            "segment": self._segments_run - 1,
-            "occupancy": occupancy,
-            "wall_seconds": wall,
-            "tokens": harvested,
-            "tokens_per_s": harvested / max(wall, 1e-12),
-            **row,
-        })
-        return harvested
+            # harvest each lane's up-stream, retire satisfied requests
+            harvested = 0
+            with jax.profiler.TraceAnnotation("engine.harvest",
+                                              lanes=occupancy):
+                for req in list(self.running.values()):
+                    data = np.asarray(self.lane_streams[req.lane].data,
+                                      np.int32)
+                    take = min(self.segment_len,
+                               req.max_new_tokens - len(req.generated))
+                    # corruption gate: a bit-flipped id is out of vocab range
+                    self.health.check_output(
+                        data[:take], lo=0, hi=self.cfg.vocab_size,
+                        source=f"lane{req.lane}",
+                        index=self._segments_run - 1)
+                    req.generated.extend(int(t) for t in data[:take])
+                    harvested += take
+                    if req.done:
+                        req.done_time = time.perf_counter()
+                        self._retire(req)
+
+            with jax.profiler.TraceAnnotation("engine.account"):
+                row = self._runner.predicted_vs_measured()
+                measured = ("bandwidth_heavy"
+                            if row["bandwidth_heavy_measured"]
+                            else "compute_bound")
+                for entry in self.admission_log:
+                    if entry["measured_verdict"] is None:
+                        entry["measured_verdict"] = measured
+                self.pool.reset_inactive(self._active)
+                self._update_degradation()
+                self._maybe_recalibrate()
+                self._expire_deadlines()
+
+                self.segment_log.append({
+                    "segment": self._segments_run - 1,
+                    "occupancy": occupancy,
+                    "wall_seconds": wall,
+                    "tokens": harvested,
+                    "tokens_per_s": harvested / max(wall, 1e-12),
+                    **row,
+                })
+            return harvested
 
     def run_until_drained(self, max_segments: int = 10_000) -> dict[int, np.ndarray]:
         """Run segments until queue + lanes are empty; returns rid -> tokens."""
@@ -861,17 +893,12 @@ class ServeEngine:
     # -- reporting ------------------------------------------------------------
 
     def stats(self) -> dict[str, Any]:
-        lat = np.asarray(self.token_latencies or [0.0])
-        decode_s = sum(s["wall_seconds"] for s in self.segment_log)
-        tokens = sum(s["tokens"] for s in self.segment_log)
+        """Counts over the engine's life. Per-segment walls and rates are in
+        ``segment_log``; time a request or a token from its own stamps."""
         return {
             "requests": len(self.finished),
             "segments": self._segments_run,
-            "tokens": tokens,
-            "decode_seconds": decode_s,
-            "tokens_per_s": tokens / max(decode_s, 1e-12),
-            "latency_p50_s": float(np.percentile(lat, 50)),
-            "latency_p99_s": float(np.percentile(lat, 99)),
+            "tokens": sum(s["tokens"] for s in self.segment_log),
             "mean_occupancy": (
                 float(np.mean([s["occupancy"] for s in self.segment_log]))
                 if self.segment_log else 0.0),

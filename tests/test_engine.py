@@ -68,8 +68,8 @@ def test_packed_batch_matches_sequential_generate(tiny):
     stats = eng.stats()
     assert stats["requests"] == 3
     assert stats["tokens"] == 3 * 8
-    assert stats["tokens_per_s"] > 0
-    assert stats["latency_p99_s"] >= stats["latency_p50_s"] > 0
+    assert stats["segments"] == 2           # all three joined at once
+    assert stats["admissions"] == 3 and stats["timed_out"] == stats["cancelled"] == 0
 
 
 def test_requests_straddle_segments_and_lanes_recycle(tiny):
