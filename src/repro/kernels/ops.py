@@ -64,7 +64,9 @@ def dot(v, u, *, token_size=8 * 1024):
     return _dot(v, u, token_size=token_size, interpret=interpret_mode())
 
 
-def attention(q, k, v, *, causal=True, sm_scale=None, block_q=128, block_kv=128):
+def attention(q, k, v, *, causal=True, sm_scale=None, block_q=None, block_kv=None):
+    """Tiles left as None are chosen from the shapes
+    (:func:`repro.kernels.flash_attention.attention_tiles`)."""
     return _attention_vjp(q, k, v, causal, sm_scale, block_q, block_kv)
 
 

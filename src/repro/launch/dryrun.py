@@ -228,7 +228,7 @@ def stream_plan_report(
     chip's slice of the cell, in the same per-device units as the roofline
     terms it sits next to.
     """
-    from repro.kernels.flash_attention import attention_plan
+    from repro.kernels.flash_attention import attention_plan, attention_tiles
     from repro.kernels.streamed_matmul import matmul_plan, plan_candidates
 
     def pick(build, candidates):
@@ -269,21 +269,20 @@ def stream_plan_report(
     skv = shape.seq_len
     d_head = cfg.head_dim_
 
-    def build_attn(block_q, block_kv):
+    hkv = max(cfg.num_kv_heads, 1)
+
+    def build_attn(block_q, block_kv, heads):
         return attention_plan(
-            batch, cfg.num_heads, max(cfg.num_kv_heads, 1),
+            batch, cfg.num_heads, hkv,
             _round_up(sq, block_q), _round_up(skv, block_kv), d_head,
-            block_q=block_q, block_kv=block_kv,
+            block_q=block_q, block_kv=block_kv, heads=heads,
             causal=True, q_offset=skv - sq, dtype=jnp.bfloat16,
         )
 
-    # mirror the kernel's bq = min(block_q, sq) clamp so the recorded block
-    # sizes are ones flash_attention actually runs (decode: block_q = 1)
-    q_cands = sorted({min(b, sq) for b in (128, 256, 512)})
-    kv_cands = sorted({min(b, skv) for b in (128, 256, 512)})
+    # the kernel picks its own hyperstep from the shapes; record that one
+    bq, bkv, heads = attention_tiles(cfg.num_heads, hkv, sq, skv, d_head)
     report["attention"] = pick(build_attn, [
-        {"block_q": bq, "block_kv": bkv} for bq in q_cands for bkv in kv_cands
-    ])
+        {"block_q": bq, "block_kv": bkv, "heads": heads}])
     return report
 
 

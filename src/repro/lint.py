@@ -126,9 +126,12 @@ def _lint_matmul() -> list[Diagnostic]:
 def _lint_attention() -> list[Diagnostic]:
     from repro.core import TPU_V5E_CHIP
     from repro.core.verify import verify_plan
-    from repro.kernels.flash_attention import attention_plan
+    from repro.kernels.flash_attention import attention_plan, attention_tiles
 
-    plan = attention_plan(1, 4, 2, 256, 256, 64, block_q=128, block_kv=128)
+    # the hyperstep the kernel itself picks for this shape
+    bq, bkv, heads = attention_tiles(4, 2, 256, 256, 64)
+    plan = attention_plan(1, 4, 2, 256, 256, 64, block_q=bq, block_kv=bkv,
+                          heads=heads)
     return verify_plan(plan, TPU_V5E_CHIP)
 
 

@@ -110,6 +110,96 @@ def test_flash_attention_bf16(rng):
                                np.asarray(want, np.float32), rtol=0.1, atol=0.1)
 
 
+def _kernel_at_128(q, k, v):
+    """The forward as it ran before tiles were chosen from the shapes: one
+    head and 128 × 128 scores a hyperstep, q, k, p and v all in float32 on
+    the MXU, the causal mask on every computed tile."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import pipeline
+    from repro.kernels.flash_attention import attention_plan
+
+    b, h, s, d = q.shape
+
+    def body(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
+        qi, ki = pl.program_id(2), pl.program_id(3)
+
+        @pl.when(ki == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, -1e30)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        @pl.when(ki <= qi)
+        def _body():
+            q_pos = qi * 128 + jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+            k_pos = ki * 128 + jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+            s_ = jnp.dot(q_ref[0, 0].astype(jnp.float32),
+                         k_ref[0, 0].astype(jnp.float32).T) * d ** -0.5
+            s_ = jnp.where(q_pos >= k_pos, s_, -1e30)
+            m_prev = m_ref[0]
+            m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
+            p = jnp.exp(s_ - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[0] = alpha * l_ref[0] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[0] = alpha * acc_ref[0] + jnp.dot(
+                p, v_ref[0, 0].astype(jnp.float32))
+            m_ref[0] = m_new
+
+        @pl.when(ki == s // 128 - 1)
+        def _store():
+            o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+    plan = attention_plan(b, h, h, s, s, d, block_q=128, block_kv=128,
+                          dtype=q.dtype)
+    return pipeline.lower(plan, body, interpret=True)(q, k, v)
+
+
+def _bf16_ulp(x, floor):
+    """One bf16 unit in the last place at |x|, taken at no less than
+    ``floor``: where a row's sum cancels to near 0, the float32 accumulator's
+    own rounding (a few 2^-24 of the terms) is more than an ulp of the
+    result, whatever the tiles."""
+    mag = np.maximum(np.abs(np.asarray(x, np.float32)), floor)
+    return np.exp2(np.floor(np.log2(mag)) - 7)
+
+
+def test_flash_attention_pretrain_tiles_match_128(rng):
+    """At the pretrain family's shape the kernel picks its own (large) tiles,
+    bf16 operands on the MXU: the result is the 128 × 128 float32 kernel's to
+    within one bf16 ulp per element, and no further from the float32 oracle
+    than it, plus one ulp."""
+    b, h, s, d = 1, 2, 2048, 64
+    q, k, v = (_rand(rng, (b, h, s, d), jnp.bfloat16) for _ in range(3))
+    out = np.asarray(flash_attention(q, k, v, interpret=True), np.float32)
+    before = np.asarray(_kernel_at_128(q, k, v), np.float32)
+    want = np.asarray(ref.attention_ref(*(x.astype(jnp.float32) for x in (q, k, v)),
+                                        causal=True))
+    floor = 2.0 ** -16 * float(jnp.max(jnp.abs(v.astype(jnp.float32))))
+    gap = np.abs(out - before)
+    assert np.all(gap <= _bf16_ulp(np.maximum(np.abs(out), np.abs(before)),
+                                   floor)), gap.max()
+    err, err_before = np.abs(out - want).max(), np.abs(before - want).max()
+    assert err <= err_before + _bf16_ulp(np.abs(want).max(), floor)
+
+
+@pytest.mark.parametrize("hq,hkv,sq,skv,d", [
+    (8, 2, 256, 256, 128),     # a head block covers two kv heads
+    (8, 2, 1024, 1024, 128),   # 512 tiles, one kv head per head block
+    (16, 2, 1024, 1024, 128),  # head block narrower than the group of 8
+    (8, 2, 1, 600, 128),       # decode row at the end of a ragged cache
+])
+def test_flash_attention_gqa_default_tiles(rng, hq, hkv, sq, skv, d):
+    q = _rand(rng, (1, hq, sq, d), jnp.bfloat16)
+    k = _rand(rng, (1, hkv, skv, d), jnp.bfloat16)
+    v = _rand(rng, (1, hkv, skv, d), jnp.bfloat16)
+    out = flash_attention(q, k, v, interpret=True)
+    want = ref.attention_ref(*(x.astype(jnp.float32) for x in (q, k, v)),
+                             causal=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want),
+                               rtol=2e-2, atol=2e-2)
+
+
 # ------------------------------------------------------------------- ssm ----
 
 

@@ -76,6 +76,54 @@ def test_causal_skip_prices_zero_flops():
     assert plan.total_flops == pytest.approx(sum(flops))
 
 
+def test_causal_kv_maps_fetch_only_computed_blocks():
+    # grid (1,1,4,4) at 512 tiles: Q row i needs K/V blocks 0..i, 10 of 16
+    plan = attention_plan(1, 1, 1, 2048, 2048, 64, block_q=512, block_kv=512,
+                          causal=True)
+    kv_tok = 512 * 64
+    coords = [(0, 0, i, j) for i in range(4) for j in range(4)]
+    computed = [c[3] <= c[2] for c in coords]
+    assert sum(computed) == 10
+    q_fetch = [plan.inputs[0].words * (c[3] == 0) for c in coords]
+    kv_fetch = [f - qf for f, qf in zip(plan.fetch_schedule(), q_fetch)]
+    assert set(kv_fetch) == {0, 2 * kv_tok}
+    fetching = [f > 0 for f in kv_fetch]
+    # a skipped step repeats the block its row left resident: no fetch, no
+    # FLOPs; row 1 also starts on block 0, which row 0 left resident
+    assert not any(f and not c for f, c in zip(fetching, computed))
+    assert [c for c, f, k in zip(coords, fetching, computed) if k and not f] \
+        == [(0, 0, 1, 0)]
+    assert sum(fetching) == 9
+    assert all(plan._flops_at(c) == 0.0 for c, k in zip(coords, computed) if not k)
+
+
+@pytest.mark.parametrize("hq,hkv,sq,skv,d", [
+    (36, 36, 2048, 2048, 64),     # minicpm-2b pretrain
+    (48, 4, 2048, 2048, 128),     # starcoder2-15b, GQA group 12
+    (48, 4, 1, 2176, 128),        # one decode row over a ragged cache
+    (32, 8, 4096, 4096, 128),
+    (4, 2, 96, 96, 32),
+    (7, 7, 300, 300, 64),         # prime head count, short ragged sequence
+])
+def test_attention_tiles_fit_and_outgrow_128(hq, hkv, sq, skv, d):
+    from repro.core import TPU_V5E_CHIP
+    from repro.kernels.flash_attention import attention_tiles
+
+    bq, bkv, heads = attention_tiles(hq, hkv, sq, skv, d)
+    plan = attention_plan(1, hq, hkv, -(-sq // bq) * bq, -(-skv // bkv) * bkv,
+                          d, block_q=bq, block_kv=bkv, heads=heads,
+                          q_offset=skv - sq)
+    assert plan.fits(TPU_V5E_CHIP)
+    group = hq // hkv
+    assert hq % heads == 0 and (heads % group == 0 or group % heads == 0)
+    if sq >= 2048:
+        # the pretrain family's length: tiles divide it, larger than 128
+        assert sq % bq == 0 and skv % bkv == 0
+        assert bq > 128 and bkv > 128
+    if (sq, d) == (2048, 64):
+        assert plan.num_hypersteps <= 1000
+
+
 def test_cost_matches_manual_eq1():
     # dot product: n hypersteps, 2C words fetched, 2C flops each; paper §3.1
     c = 1024

@@ -57,6 +57,18 @@ def test_flash_attention_compiles(one_chip):
     assert "tpu_custom_call" in _compile(one_chip, flash_attention, qkv, qkv, qkv)
 
 
+@pytest.mark.parametrize("q,kv", [
+    ((2, 36, 2048, 64), (2, 36, 2048, 64)),      # minicpm-2b pretrain
+    ((1, 48, 2048, 128), (1, 4, 2048, 128)),     # starcoder2-15b, GQA 12
+    ((1, 48, 1, 128), (1, 4, 2176, 128)),        # a decode row, ragged cache
+])
+def test_flash_attention_chosen_tiles_compile(one_chip, q, kv):
+    """The hyperstep the kernel picks from the shapes fits the chip's
+    scoped VMEM (the compiler refuses a score tile too large for it)."""
+    assert "tpu_custom_call" in _compile(one_chip, flash_attention, (q, BF16),
+                                         (kv, BF16), (kv, BF16))
+
+
 @pytest.mark.parametrize("m", [1, 4, 2048])
 def test_streamed_matmul_compiles(one_chip, m):
     """minicpm-2b's MLP up-projection at decode and prefill widths."""
