@@ -1,4 +1,4 @@
-"""Serve cells: the program's ``ServeEngine`` under a request mix.
+"""The serve driver: the program's ``ServeEngine`` under a request mix.
 
 Set-up draws the weights, builds the engine at the cell's lanes, pool and
 segment length, and warms it with requests of every prompt length of the
@@ -14,11 +14,13 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import json
 import time
 
 import numpy as np
 
-from bench import reference, spec, weights
+from bench import spec, weights
+from bench.spec import say
 
 clock = time.perf_counter
 
@@ -39,25 +41,29 @@ class Tracked:
     stamps: list = dataclasses.field(default_factory=list)   # (segment end, served)
 
 
-def build(cell: spec.Cell, seed: int):
+def build(cell: spec.Cell, seed: int, tmp: str | None = None):
     """(engine, generator): weights from the seed, the engine built with the
     workload file's ``engine`` settings as its keyword arguments."""
     from repro.launch.engine import ServeEngine
 
-    c, e = cell.config, cell.workload["engine"]
-    cfg = spec.program_config(c, cell.workload.get("program"))
-    params = weights.to_program(c, weights.make(c, seed, c["torch_dtype"]), cfg.scan_layers)
+    c, e, fam = cell.config, cell.workload["engine"], cell.family
+    cfg = fam.program_config(c, cell.workload.get("program"))
+    params = fam.to_program(c, weights.make(fam.layout(c), seed, c["torch_dtype"]),
+                            cfg.scan_layers)
     eng = ServeEngine(cfg, params, **e)
     return eng, spec.generator(cell, seed)
 
 
-def warm(eng, gen, rounds: int = 2) -> float:
+def warm(sut, rounds: int = 2) -> None:
     """Every shape the window uses: one prefill per prompt length of the
     ladder, the segment program, the joins and the retirements. Then one
     full collection, and every object alive is frozen out of the garbage
     collector's later passes (as servers do once warm), so that no
     collection walks the traced programs inside the window; ``gc.unfreeze``
-    undoes it. Returns the collection's seconds."""
+    undoes it."""
+    from repro.launch.serve import prefill_block_size
+
+    eng, gen = sut
     rng = np.random.default_rng([gen.seed, 3])
     for _ in range(rounds):
         for plen in gen.ladder:
@@ -67,12 +73,19 @@ def warm(eng, gen, rounds: int = 2) -> float:
     t = clock()
     gc.collect()
     gc.freeze()
-    return clock() - t
+    say(f"a full collection after warming took {clock() - t:.3f} s; "
+        f"{gc.get_freeze_count()} objects frozen")
+    m = eng.machine
+    blocks = {p: prefill_block_size(eng.cfg, 1, p, m) for p in gen.ladder}
+    say(f"machine pack {m.name!r} (r {m.r:.6g}, g {m.g:.6g}, l {m.l:.6g}, "
+        f"e {m.e:.6g}); prefill blocks " + json.dumps(blocks))
 
 
-def window(eng, gen, seconds: float, annotate, tick=None) -> dict:
-    """Drive the engine for ``seconds``; every submitted request's stamps.
-    ``tick`` is called before each round of submits and a segment."""
+def window(sut, seconds: float, annotate, tick=None) -> dict:
+    """Drive the engine for ``seconds`` with the generator's requests; every
+    submitted request's stamps. ``tick`` is called before each round of
+    submits and a segment."""
+    eng, gen = sut
     lanes = eng.max_lanes
     queued = gen.mix["arrivals"].get("queued_per_lane", 0) * lanes
     tracked: dict[int, Tracked] = {}
@@ -154,6 +167,10 @@ def window(eng, gen, seconds: float, annotate, tick=None) -> dict:
                "degraded_segments": degraded,
                "slowest_steps": [[round(x, 4) for x in row]
                                  for row in sorted(slow, reverse=True)[:3]]}
+    say(f"submit lag behind due time: max {max(lateness, default=0):.6f} s, mean "
+        f"{sum(lateness) / max(len(lateness), 1):.6f} s over {len(lateness)} requests")
+    say("engine in the window (the slowest steps: [seconds, joins, their "
+        "prefill s, segment s]) " + json.dumps(program))
     return {"t0": t0, "t1": t1, "seconds": seconds, "requests": list(tracked.values()),
             "segments": segments, "lateness": lateness, "program": program}
 
@@ -204,7 +221,31 @@ def percentile(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
-def check(cell: spec.Cell, seed: int, rec: dict, control: bool = False) -> dict:
+def tally(cell: spec.Cell, rec: dict, warmed=None) -> tuple[int, int]:
+    """(requests submitted, finished requests served the wrong count of
+    tokens or an id outside the vocabulary)."""
+    vocab = cell.config["vocab_size"]
+    failed = sum(1 for r in rec["requests"] if r.tokens is not None
+                 and (len(r.tokens) != r.new_tokens
+                      or any(not 0 <= t < vocab for t in r.tokens)))
+    return len(rec["requests"]), failed
+
+
+def for_readers(cell: spec.Cell, rec: dict) -> dict:
+    """Each request's pending interval (due to done, else the record's
+    end), the prefills' seconds and model FLOPs, and the model FLOPs of the
+    prefills and the tokens served."""
+    fam, c = cell.family, cell.config
+    reqs = rec["requests"]
+    prefill = sum(fam.prefill_flops(c, len(r.prompt)) for r in reqs if r.prefill_s > 0)
+    return {"pending": [(r.due, r.done if r.done is not None else rec["t1"]) for r in reqs],
+            "prefill_s": sum(r.prefill_s for r in reqs), "prefill_flops": prefill,
+            "model_flops": prefill + sum(fam.decode_flops(c, len(r.prompt), r.served)
+                                         for r in reqs)}
+
+
+def check(cell: spec.Cell, seed: int, rec: dict, warmed=None,
+          control: bool = False) -> dict:
     """Compare a sample of the window's finished requests with the reference.
 
     The sample, drawn from the seed, holds the request that was served most
@@ -217,7 +258,7 @@ def check(cell: spec.Cell, seed: int, rec: dict, control: bool = False) -> dict:
     """
     import jax
 
-    c, chk = cell.config, cell.workload["check"]
+    c, chk, fam = cell.config, cell.workload["check"], cell.family
     pool = cell.workload["engine"]["pool_seq"]
     done = [r for r in rec["requests"] if r.tokens is not None]
     missing = sum(abs(len(r.tokens) - r.new_tokens) for r in done)
@@ -231,7 +272,7 @@ def check(cell: spec.Cell, seed: int, rec: dict, control: bool = False) -> dict:
     pick = [longest] + list(np.random.default_rng([seed, 4]).permutation(rest)[
         : chk["requests"] - 1])
     sample = [done[j] for j in pick]
-    w = weights.make(c, seed, c["torch_dtype"])
+    w = weights.make(fam.layout(c), seed, c["torch_dtype"])
     group = chk["group"]
     gaps, ctrl, served = [], [], 0
     for k in range(0, len(sample), group):
@@ -243,7 +284,7 @@ def check(cell: spec.Cell, seed: int, rec: dict, control: bool = False) -> dict:
             toks[j, :len(seq)] = seq
             mask[j, len(r.prompt) - 1:len(seq) - 1] = True
         g, g8 = (np.asarray(x) for x in jax.device_get(
-            reference.token_gaps(c, w, toks, fp8=control)))
+            fam.token_gaps(c, w, toks, fp8=control)))
         gaps.append(g[mask])
         ctrl.append(g8[mask])
         served += int(mask.sum())
@@ -254,3 +295,36 @@ def check(cell: spec.Cell, seed: int, rec: dict, control: bool = False) -> dict:
     if control:
         out["control_max_gap_sd"] = float(np.max(np.concatenate(ctrl)))
     return out
+
+
+def rehearse(cell: spec.Cell, place, report) -> None:
+    """Compile the engine's segment program and the prefill at every prompt
+    length of the ladder (one chunk of the whole prompt: the largest block
+    the engine can choose). ``place`` gives a tree's shapes on the described
+    device; ``report`` prints a compiled program's memory."""
+    import jax
+
+    from repro.launch.engine import ServeEngine
+    from repro.launch.serve import make_prefill
+    from repro.models import model as M
+
+    c, e, fam = cell.config, cell.workload["engine"], cell.family
+    cfg = fam.program_config(c, cell.workload.get("program"))
+    params = place(jax.eval_shape(lambda: fam.to_program(
+        c, weights.make(fam.layout(c), 0, c["torch_dtype"]), cfg.scan_layers)))
+    eng = ServeEngine(cfg, params, max_lanes=e["max_lanes"], pool_seq=e["pool_seq"],
+                      segment_len=e["segment_len"])
+    runner = eng._runner
+    prog = runner._compiled_cache[eng.segment_len]
+    state = (eng._logits, eng.pool.cache, eng._keys, np.asarray(eng._active))
+    stacked = [[s.as_stacked() for s in ss] for ss in runner._streams]
+    out_bufs = [[s.as_stacked() for s in outs] for outs in runner._out_streams]
+    report(f"{cell.name} segment ({e['max_lanes']} lanes x {e['pool_seq']}, "
+           f"{eng.segment_len} steps)",
+           prog._call.lower(place(state), place(out_bufs), place(stacked), params).compile())
+    del eng
+    cache = place(jax.eval_shape(lambda: M.init_cache(cfg, 1, e["pool_seq"])))
+    for plen in cell.traffic["prompt_len"]["ladder"]:
+        prompt = place(jax.ShapeDtypeStruct((1, plen), np.int32))
+        report(f"{cell.name} prefill {plen} (block {plen})",
+               make_prefill(cfg, plen).lower(params, cache, prompt).compile())

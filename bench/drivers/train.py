@@ -1,4 +1,4 @@
-"""Train cells: the compiled train segment that ``train()`` runs.
+"""The train driver: the compiled train segment that ``train()`` runs.
 
 ``train()`` draws its parameters eagerly and calibrates inside the call, so
 the harness builds the same segment program itself, as
@@ -14,6 +14,8 @@ the same runner on the same state.
 from __future__ import annotations
 
 import gc
+import os
+import tempfile
 import time
 
 import numpy as np
@@ -42,7 +44,7 @@ class Trainer:
         from repro.optim.schedule import constant
         from repro.train.steps import make_train_step
 
-        c, o = cell.config, cell.config["optimizer"]
+        c, o, fam = cell.config, cell.config["optimizer"], cell.family
         self.cell, self.seed = cell, seed
         self.steps = cell.workload["segment_steps"]
         if self.steps != 1:
@@ -51,7 +53,7 @@ class Trainer:
         gen = spec.generator(cell, seed)
         self.gen = gen
         gen.write(token_file)
-        cfg = spec.program_config(c, cell.workload.get("program"))
+        cfg = fam.program_config(c, cell.workload.get("program"))
         self.opt = AdamW(schedule=constant(o["lr"]), b1=o["b1"], b2=o["b2"], eps=o["eps"],
                          weight_decay=o["weight_decay"], grad_clip=o["grad_clip"])
         step_fn = jax.jit(make_train_step(cfg, self.opt), donate_argnums=(0, 1))
@@ -60,11 +62,11 @@ class Trainer:
         spec_batch = {k: jax.ShapeDtypeStruct((gen.batch, gen.seq_len), jnp.int32)
                       for k in ("tokens", "labels")}
         if abstract:       # shapes only, for a compile without the chip
-            params = jax.eval_shape(lambda: weights.to_program(
-                c, weights.make(c, seed, c["torch_dtype"])))
+            params = jax.eval_shape(lambda: fam.to_program(
+                c, weights.make(fam.layout(c), seed, c["torch_dtype"])))
             opt_state = jax.eval_shape(self.opt.init, params)
         else:
-            params = weights.to_program(c, weights.make(c, seed, c["torch_dtype"]))
+            params = fam.to_program(c, weights.make(fam.layout(c), seed, c["torch_dtype"]))
             opt_state = jax.jit(self.opt.init)(params)
         _, _, shapes = jax.eval_shape(step_fn, params, opt_state, spec_batch)
         self.mkeys = sorted(k for k, v in shapes.items() if v.size == 1)
@@ -94,25 +96,31 @@ class Trainer:
                 for i in range(self.steps)]
 
 
-def first_steps(tr: Trainer) -> dict:
+def build(cell: spec.Cell, seed: int, tmp: str) -> Trainer:
+    """The compiled train segment with its state; its token file in ``tmp``."""
+    return Trainer(cell, seed, os.path.join(tmp, "tokens.u32"))
+
+
+def warm(tr: Trainer) -> dict:
     """Steps 1..3 through the window's own call: each loss, the first
     gradient as the optimizer got it (its first moment over 1 - b1, read
     after step 1), and the parameters' change after step 3."""
     import jax
 
-    c = tr.cell.config
+    c, fam = tr.cell.config, tr.cell.family
     b1 = c["optimizer"]["b1"]
+    stacked = fam.stacked(c)
     norms = jax.jit(lambda t: reference.leaf_norms(
-        {k: v / (1 - b1) for k, v in weights.from_program(t).items()}))
+        {k: v / (1 - b1) for k, v in fam.from_program(t).items()}, stacked))
     out = {"losses": []}
     while tr.done < CHECK_STEPS:
         out["losses"] += [m["loss"] for m in tr.segment()]
         if tr.done == 1:
             out["grad_norms"] = jax.device_get(norms(tr.state[1]["m"]))
-    w0 = weights.make(c, tr.seed, c["torch_dtype"])
+    w0 = weights.make(fam.layout(c), tr.seed, c["torch_dtype"])
     out["change_norms"] = jax.device_get(jax.jit(lambda p, q: reference.leaf_norms(
         {k: v.astype("float32") - q[k].astype("float32")
-         for k, v in weights.from_program(p).items()}))(tr.state[0], w0))
+         for k, v in fam.from_program(p).items()}, stacked))(tr.state[0], w0))
     del w0
     return out
 
@@ -144,6 +152,7 @@ def upto(rec: dict, cut: float) -> dict:
 
 
 def e2e(rec: dict) -> dict:
+    """The end-to-end numbers of a train window."""
     steps = sum(s["steps"] for s in rec["segments"])
     return {"train_tok_s": steps * rec["tokens_per_step"] / (rec["t1"] - rec["t0"]),
             "counts": {"steps": steps, "segments": len(rec["segments"])}}
@@ -160,12 +169,26 @@ def leaf_gap(cand: dict, base: dict, skip: set) -> tuple[float, str]:
                 if name not in skip), default=(0.0, ""))
 
 
-def readings(c: dict, seed: int, batches: list, fp8: bool = False) -> dict:
+def tally(cell: spec.Cell, rec: dict, first: dict) -> tuple[int, int]:
+    """(steps taken, set-up's checked steps included; those whose loss is
+    not a number)."""
+    return (sum(s["steps"] for s in rec["segments"]) + CHECK_STEPS,
+            sum(1 for x in first["losses"] if x != x))
+
+
+def for_readers(cell: spec.Cell, rec: dict) -> dict:
+    """The model FLOPs of the steps taken."""
+    return {"model_flops": sum(s["steps"] for s in rec["segments"]) * rec["tokens_per_step"]
+            * cell.family.train_token_flops(cell.config, cell.traffic["seq_len"])}
+
+
+def readings(cell: spec.Cell, seed: int, batches: list, fp8: bool = False) -> dict:
     """The reference's losses, first-gradient and change norms over ``batches``."""
-    ref = reference.train(c, lambda: weights.make(c, seed, c["torch_dtype"]), batches,
-                          fp8=fp8)
-    ref["change_norms"] = reference.change_norms(ref.pop("w"),
-                                                 weights.make(c, seed, c["torch_dtype"]))
+    c, fam = cell.config, cell.family
+    ref = fam.train(c, lambda: weights.make(fam.layout(c), seed, c["torch_dtype"]), batches,
+                    fp8=fp8)
+    ref["change_norms"] = reference.change_norms(
+        ref.pop("w"), weights.make(fam.layout(c), seed, c["torch_dtype"]), fam.stacked(c))
     gc.collect()
     return ref
 
@@ -186,16 +209,31 @@ def compare(cand: dict, base: dict) -> dict:
             "skipped_leaves": sorted(skip)}
 
 
-def check(cell: spec.Cell, seed: int, prog: dict, control: bool = False) -> dict:
-    """The reference follows the first three steps from the same weights and
-    rows; with ``control``, so does the fp8 reference in the program's place."""
-    c = cell.config
+def check(cell: spec.Cell, seed: int, rec: dict, prog: dict, control: bool = False) -> dict:
+    """The reference follows the first three steps (``prog``, from ``warm``)
+    from the same weights and rows; with ``control``, so does the fp8
+    reference in the program's place."""
     gen = spec.generator(cell, seed)
     batches = [gen.batch_at(i) for i in range(CHECK_STEPS)]
-    base = readings(c, seed, batches)
+    base = readings(cell, seed, batches)
     out = compare(prog, base)
     out["losses"], out["reference_losses"] = prog["losses"], base["losses"]
     if control:
-        ctrl = compare(readings(c, seed, batches, fp8=True), base)
+        ctrl = compare(readings(cell, seed, batches, fp8=True), base)
         out.update({f"control_{k}": v for k, v in ctrl.items() if k != "skipped_leaves"})
     return out
+
+
+def rehearse(cell: spec.Cell, place, report) -> None:
+    """Compile the train segment. ``place`` gives a tree's shapes on the
+    described device; ``report`` prints a compiled program's memory."""
+    with tempfile.TemporaryDirectory() as tmp:
+        tr = Trainer(cell, 0, os.path.join(tmp, "tokens.u32"), abstract=True)
+        runner = tr.runner
+        prog = runner.compile(tr.steps)
+        stacked = [[s.as_stacked() for s in ss] for ss in runner._streams]
+        out_bufs = [[s.as_stacked() for s in outs] for outs in runner._out_streams]
+        report(f"{cell.name} train segment ({tr.steps} step, batch "
+               f"{tr.gen.batch} x {tr.gen.seq_len})",
+               prog._call.lower(place(tr.state), place(out_bufs), place(stacked),
+                                None).compile())

@@ -35,6 +35,7 @@ from typing import NamedTuple
 import numpy as np
 
 from bench import trace as T
+from bench.spec import say
 
 PROGRAM = ("engine.", "runtime.")
 
@@ -110,7 +111,8 @@ def spans(rec: dict) -> list[Span] | None:
             return None
         began = time.perf_counter()
         every, (starts, ends) = read_profile(path)
-        lo, hi = T.Trace(devices=[], spans=[(s.start, s.end, s.name) for s in every]).window()
+        lo, hi = t.get("bounds") or T.Trace(
+            devices=[], spans=[(s.start, s.end, s.name) for s in every]).window()
         inside = [s for s in every if lo <= s.start and s.end <= hi]
         t["spans"] = [s for s in inside if s.name.startswith(PROGRAM)]
         idle = gaps(starts, ends, lo, hi) if len(starts) else ([], [])
@@ -196,10 +198,6 @@ def self_seconds(found) -> dict[str, list]:
         row[1] += s.end - s.start
         row[2] += (s.end - s.start) - covered(s, program)
     return out
-
-
-def say(*parts) -> None:
-    print("[bench]", *parts, file=sys.stderr, flush=True)
 
 
 def report(found, idle, read_s: float) -> None:

@@ -7,7 +7,7 @@ name under ``bench/`` (see ``bench/spec.py``). A run draws its weights and
 traffic from ``--seed``, warms every shape its traffic uses (set-up), drives
 the program for ``--seconds`` (the window), reads the device's peak memory,
 frees the program and compares a sample of what the window produced with
-the plain float32 reference (``bench/reference.py``). The last line of
+the plain float32 reference (its family's, ``bench/families``). The last line of
 standard output is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer metrics, read from a profiler trace), ``device``, ``breakdown``
@@ -133,9 +133,11 @@ class Tracer:
         jax.profiler.stop_trace()
 
 
-def reduce_trace(path: str, c: dict, rec: dict, peak: dict) -> tuple[dict, dict]:
-    """(summary for the readers, breakdown) of the traced part of the window."""
-    from bench import flops, trace as T
+def reduce_trace(path: str, cell, rec: dict, peak: dict) -> tuple[dict, dict]:
+    """(summary for the readers, breakdown) of the traced part of the window.
+    Every kernel with a cost file (``bench/kernels``) and calls in the trace
+    gets its calls' device time and least time."""
+    from bench import flops, spec, trace as T
 
     tr = T.load(path)
     lo, hi = tr.window()
@@ -147,19 +149,20 @@ def reduce_trace(path: str, c: dict, rec: dict, peak: dict) -> tuple[dict, dict]
         off = lo - rec["t0"]
         within = T.clip(T.merge([(s + off, e + off) for s, e in pending]), lo, hi)
     kernels = {}
-    for name in ("streamed_matmul", "flash_attention"):
+    for name, kernel in sorted(spec.kernels().items()):
         calls = T.kernel_calls(tr, name)
         if not calls:
             continue
-        least = [flops.least_seconds(*flops.kernel_cost(name, k.operands, c), peak)
+        least = [flops.least_seconds(*kernel.cost(k.operands, cell.config, cell.family), peak)
                  for k in calls]
         kernels[name] = {"calls": len(calls),
                          "seconds": sum(k.device_seconds for k in calls),
                          "fed_s": sum(k.fed_seconds for k in calls),
                          "least_s": sum(t for t, _ in least),
                          "compute_bound_s": sum(t for t, b in least if b == "compute"),
-                         "max_call_share": max(t / k.device_seconds
-                                               for k, (t, _) in zip(calls, least))}
+                         "max_call_share": max((t / k.device_seconds
+                                                for k, (t, _) in zip(calls, least)
+                                                if k.device_seconds > 0), default=None)}
         by_shape: dict[str, list[float]] = {}
         for k, (t, _) in zip(calls, least):
             row = by_shape.setdefault(str(k.operands), [0, 0.0, 0.0, 0.0, 0.0])
@@ -167,10 +170,12 @@ def reduce_trace(path: str, c: dict, rec: dict, peak: dict) -> tuple[dict, dict]
             row[1] += k.seconds
             row[2] += k.fed_seconds
             row[3] += t
-            row[4] = max(row[4], t / k.device_seconds)
+            if k.device_seconds > 0:
+                row[4] = max(row[4], t / k.device_seconds)
         say(f"{name} by operand shapes: [calls, kernel s, fed s, least s, "
             f"largest share of one call] " + json.dumps(by_shape))
-    summary = {"busy_s": T.busy(tr), "window_s": hi - lo, "kernels": kernels}
+    summary = {"busy_s": T.busy(tr), "window_s": hi - lo, "bounds": [lo, hi],
+               "kernels": kernels}
     if within is not None:
         summary.update(busy_pending_s=T.busy(tr, within), pending_s=T.total(within))
     return summary, {"device_ops": T.top_ops(tr), "idle_gaps": T.idle_breakdown(tr)}
@@ -179,32 +184,21 @@ def reduce_trace(path: str, c: dict, rec: dict, peak: dict) -> tuple[dict, dict]
 def run_cell(cell, seed: int, seconds: float, trace: bool, *, control: bool = False,
              t_start: float | None = None) -> dict:
     """One run; returns the result line's object. Needs no chip: the caller
-    has made sure there is one."""
+    has made sure there is one. The cell's driver (``bench/drivers``) builds,
+    warms and drives the system and judges what it produced."""
     import jax
 
-    from bench import flops, peaks, serve, train
+    from bench import peaks
 
     t_start = clock() if t_start is None else t_start
     annotate = jax.profiler.TraceAnnotation
-    c = cell.config
-    kind = cell.workload["driver"]
-    driver = serve if kind == "serve" else train
+    driver = cell.driver
     tmp = tempfile.mkdtemp(prefix="bench-")
     try:
         t_build = clock()
-        if kind == "serve":
-            eng, gen = serve.build(cell, seed)
-            t_warm = clock()
-            collect_s = serve.warm(eng, gen)
-            say(f"a full collection after warming took {collect_s:.3f} s; "
-                f"{gc.get_freeze_count()} objects frozen")
-            m = eng.machine
-            say(f"machine pack {m.name!r} (r {m.r:.6g}, g {m.g:.6g}, l {m.l:.6g}, "
-                f"e {m.e:.6g}); prefill blocks " + json.dumps(_prefill_blocks(eng, gen)))
-        else:
-            tr = train.Trainer(cell, seed, os.path.join(tmp, "tokens.u32"))
-            t_warm = clock()
-            first = train.first_steps(tr)
+        sut = driver.build(cell, seed, tmp)
+        t_warm = clock()
+        warmed = driver.warm(sut)
         setup_s = clock() - t_start
         say(f"set-up {setup_s:.3f} s: start {t_build - t_start:.3f}, build "
             f"{t_warm - t_build:.3f}, warm {clock() - t_warm:.3f}")
@@ -215,18 +209,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, control: bool = Fa
             tracer.start()
         tick = tracer.poll if tracer else None
         with Compiles() as compiles:
-            if kind == "serve":
-                rec = serve.window(eng, gen, seconds, annotate, tick)
-            else:
-                rec = train.window(tr, seconds, annotate, tick)
+            rec = driver.window(sut, seconds, annotate, tick)
         in_window = compiles.n
         if tracer:
             tracer.stop()
         device = device_info()
-        if kind == "serve":
-            del eng
-        else:
-            del tr
+        del sut
         gc.collect()
 
         e2e = driver.e2e(rec)
@@ -234,21 +222,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, control: bool = Fa
         say(f"window {rec['t1'] - rec['t0']:.4f} s, counts {json.dumps(counts)}, "
             f"compiles in the window {in_window}, peak HBM {device['memory_peak_bytes']}")
         limits = cell.workload["check"]["limits"]
-        if kind == "serve":
-            lat = rec["lateness"]
-            say(f"submit lag behind due time: max {max(lat, default=0):.6f} s, "
-                f"mean {sum(lat) / max(len(lat), 1):.6f} s over {len(lat)} requests")
-            say("engine in the window (the slowest steps: [seconds, joins, their "
-                "prefill s, segment s]) " + json.dumps(rec["program"]))
-            chk = serve.check(cell, seed, rec, control=control)
-            attempted = len(rec["requests"])
-            failed = sum(1 for r in rec["requests"] if r.tokens is not None
-                         and (len(r.tokens) != r.new_tokens
-                              or any(not 0 <= t < c["vocab_size"] for t in r.tokens)))
-        else:
-            chk = train.check(cell, seed, first, control=control)
-            attempted = sum(s["steps"] for s in rec["segments"]) + train.CHECK_STEPS
-            failed = sum(1 for x in first["losses"] if x != x)
+        chk = driver.check(cell, seed, rec, warmed, control=control)
+        attempted, failed = driver.tally(cell, rec, warmed)
         if control:             # the control in the program's place
             say("the program's own numbers " + json.dumps({k: chk.get(k) for k in limits}))
             chk = {**chk, **{k: chk[f"control_{k}"] for k in limits if f"control_{k}" in chk}}
@@ -266,19 +241,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, control: bool = Fa
                 f"over its first {rec['t1'] - rec['t0']:.4f} s")
             rec["window_s"] = rec["t1"] - rec["t0"]
             rec["peak"] = peak
-            if kind == "serve":
-                rec["pending"] = [(r.due, r.done if r.done is not None else rec["t1"])
-                                  for r in rec["requests"]]
-                rec["prefill_s"] = sum(r.prefill_s for r in rec["requests"])
-                rec["prefill_flops"] = sum(flops.prefill_flops(c, len(r.prompt))
-                                           for r in rec["requests"] if r.prefill_s > 0)
-                rec["model_flops"] = rec["prefill_flops"] + sum(
-                    flops.decode_flops(c, len(r.prompt), r.served) for r in rec["requests"])
-            else:
-                rec["model_flops"] = (sum(s["steps"] for s in rec["segments"])
-                                      * rec["tokens_per_step"]
-                                      * flops.train_token_flops(c, cell.traffic["seq_len"]))
-            summary, breakdown = reduce_trace(str(path), c, rec, peak)
+            rec.update(driver.for_readers(cell, rec))
+            summary, breakdown = reduce_trace(str(path), cell, rec, peak)
             rec["trace"] = summary
             device.update(busy_s=summary["busy_s"], window_s=summary["window_s"])
             say("trace " + json.dumps(summary))
@@ -304,12 +268,6 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, control: bool = Fa
     finally:
         gc.unfreeze()
         shutil.rmtree(tmp, ignore_errors=True)
-
-
-def _prefill_blocks(eng, gen) -> dict:
-    from repro.launch.serve import prefill_block_size
-
-    return {p: prefill_block_size(eng.cfg, 1, p, eng.machine) for p in gen.ladder}
 
 
 def main(argv: list[str] | None = None) -> int:

@@ -1,16 +1,23 @@
 """A cell, found by its name: ``BENCHMARK.json`` names its configuration and
 traffic; ``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``
-and ``bench/workloads/<cell>.json`` hold them."""
+and ``bench/workloads/<cell>.json`` hold them. The configuration file names
+its family (``bench/families/<family>.py``), the workload file its driver
+(``bench/drivers/<driver>.py``); a per-layer metric ``<kernel>_roofline.*``
+reads the kernel whose cost is ``bench/kernels/<kernel>.py``."""
 
 from __future__ import annotations
 
 import dataclasses
 import importlib
 import json
+import pkgutil
+import sys
 from pathlib import Path
+from types import ModuleType
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+ROOFLINE = "_roofline"
 
 
 @dataclasses.dataclass
@@ -22,6 +29,12 @@ class Cell:
     chips: int
     end_to_end: list    # BENCHMARK.json metric entries this cell reports
     per_layer: list
+    family: ModuleType  # bench/families/<config's family>.py
+    driver: ModuleType  # bench/drivers/<workload's driver>.py
+
+
+def say(*parts) -> None:
+    print("[bench]", *parts, file=sys.stderr, flush=True)
 
 
 def _json(path: Path) -> dict:
@@ -31,6 +44,26 @@ def _json(path: Path) -> dict:
 
 def manifest() -> dict:
     return _json(ROOT / "BENCHMARK.json")
+
+
+def module(kind: str, name: str) -> ModuleType:
+    """``bench/<kind>/<name>.py``, imported by name; an error that names the
+    file where there is none."""
+    full = f"bench.{kind}.{name}"
+    try:
+        return importlib.import_module(full)
+    except ModuleNotFoundError as e:
+        if e.name != full:
+            raise
+        where = Path(importlib.import_module(f"bench.{kind}").__path__[0]) / f"{name}.py"
+        raise FileNotFoundError(f"no {name!r} in bench/{kind}: {where} does not exist") from None
+
+
+def kernels() -> dict[str, ModuleType]:
+    """Every kernel cost file present, by kernel name."""
+    pkg = importlib.import_module("bench.kernels")
+    return {m.name: module("kernels", m.name)
+            for m in pkgutil.iter_modules(pkg.__path__) if not m.name.startswith("_")}
 
 
 def cell(name: str) -> Cell:
@@ -43,10 +76,17 @@ def cell(name: str) -> Cell:
     names = {x["name"] for x in e2e}
     layer = [x for x in m["per_layer"]
              if (name in x["workloads"] if "workloads" in x else x["moves"] in names)]
-    return Cell(name=name, config=_json(ROOT / conf["file"]),
+    config = _json(ROOT / conf["file"])
+    workload = _json(BENCH / "workloads" / f"{name}.json")
+    for x in layer:
+        kernel, found, _ = x["name"].partition(ROOFLINE)
+        if found:
+            module("kernels", kernel)
+    return Cell(name=name, config=config,
                 traffic=_json(BENCH / "traffic" / f"{entry['traffic']}.json"),
-                workload=_json(BENCH / "workloads" / f"{name}.json"),
-                chips=entry["chips"], end_to_end=e2e, per_layer=layer)
+                workload=workload, chips=entry["chips"], end_to_end=e2e, per_layer=layer,
+                family=module("families", config["family"]),
+                driver=module("drivers", workload["driver"]))
 
 
 def generator(cell: Cell, seed: int):
@@ -55,28 +95,3 @@ def generator(cell: Cell, seed: int):
     mod = importlib.import_module(f"bench.traffic.{kind}")
     cls = getattr(mod, kind.capitalize())
     return cls(cell.traffic, seed, cell.config["vocab_size"])
-
-
-def program_config(c: dict, options: dict | None = None):
-    """The program's ``ModelConfig`` for the configuration file ``c``: the
-    registry entry at ``c["num_hidden_layers"]`` layers, with the cell's
-    program ``options`` (``scan_layers``, ``remat``), refused if any width
-    differs from the file."""
-    from repro.configs import get_config
-
-    cfg = get_config(c["registry"], smoke=c.get("registry_smoke", False))
-    opts = {"scan_layers": True, **(options or {})}
-    cfg = dataclasses.replace(cfg, num_layers=c["num_hidden_layers"], **opts)
-    act = {"silu": "swiglu", "gelu_pytorch_tanh": "gelu"}[c["hidden_act"]]
-    want = {"d_model": c["hidden_size"], "num_heads": c["num_attention_heads"],
-            "num_kv_heads": c["num_key_value_heads"], "head_dim_": c["head_dim"],
-            "d_ff": c["intermediate_size"], "vocab_size": c["vocab_size"],
-            "padded_vocab": c["vocab_size"], "mlp_activation": act,
-            "norm_type": c["norm_type"], "norm_eps": c["norm_eps"],
-            "rope_theta": c["rope_theta"], "rope_type": "rope",
-            "tie_embeddings": c["tie_word_embeddings"], "dtype": c["torch_dtype"]}
-    bad = {k: (getattr(cfg, k), v) for k, v in want.items() if getattr(cfg, k) != v}
-    if bad or any(b.mixer != "attn" or b.mlp != "dense" for b in cfg.pattern):
-        raise ValueError(f"{c['registry']}: the program's config differs from "
-                         f"the configuration file: {bad}")
-    return cfg

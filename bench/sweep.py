@@ -41,7 +41,8 @@ def main(argv: list[str] | None = None) -> int:
     import jax
     import numpy as np
 
-    from bench import serve, spec
+    from bench import spec
+    from bench.drivers import serve
     from repro.launch.compile_cache import enable_compile_cache
 
     if jax.devices()[0].platform != "tpu":
@@ -50,7 +51,7 @@ def main(argv: list[str] | None = None) -> int:
     enable_compile_cache()
     cell = spec.cell(args.workload)
     eng, gen = serve.build(cell, args.seed)
-    serve.warm(eng, gen)
+    serve.warm((eng, gen))
     runs = [(float(r), int(z)) for r in args.rates.split(",")
             for z in args.sizes_seeds.split(",")]
     for rate, sizes_seed in runs:
@@ -59,7 +60,7 @@ def main(argv: list[str] | None = None) -> int:
         c.traffic["arrivals"]["rate_per_s"] = rate
         c.traffic["sizes_seed"] = sizes_seed
         g = spec.generator(c, args.seed)
-        rec = serve.window(eng, g, args.seconds, jax.profiler.TraceAnnotation)
+        rec = serve.window((eng, g), args.seconds, jax.profiler.TraceAnnotation)
         reqs = rec["requests"]
         ttft = [(r.first if r.first is not None else rec["t1"]) - r.due for r in reqs]
         row = {"rate_per_s": rate, "sizes_seed": sizes_seed, "due": len(reqs),

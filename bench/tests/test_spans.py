@@ -42,9 +42,9 @@ def traced_run(monkeypatch, name: str) -> tuple[dict, dict]:
     seen = {}
     reduce = R.reduce_trace
 
-    def spy(path, c, rec, peak):
+    def spy(path, cell, rec, peak):
         seen["rec"] = rec
-        return reduce(path, c, rec, peak)
+        return reduce(path, cell, rec, peak)
 
     monkeypatch.setattr(R, "reduce_trace", spy)
     monkeypatch.setattr(peaks, "peaks", lambda kind: peaks.PEAKS["TPU v5 lite"])
@@ -205,3 +205,20 @@ def test_the_recorded_trace_read_for_spans(recorded):
     idle = _spans.gaps(starts, ends, lo, hi)
     assert list(zip(*(x.tolist() for x in idle))) == T.gaps(recorded)
     assert _spans.idle_by_span(found, idle)[0][0] == "bench.sleep"
+
+
+def test_the_spans_are_read_over_the_window_the_harness_found(tmp_path, monkeypatch):
+    """The program spans' reader takes the traced part that ``reduce_trace``
+    found, so a profile whose harness spans were dropped still reads."""
+    from types import SimpleNamespace
+
+    where = tmp_path / "plugins" / "profile" / "run"
+    where.mkdir(parents=True)
+    (where / "host.xplane.pb").write_bytes(DATA.read_bytes())
+    found, ops = _spans.read_profile(str(DATA))
+    monkeypatch.setattr(_spans, "read_profile", lambda path: (
+        [s for s in found if not s.name.startswith("bench.")], ops))
+    tracer = SimpleNamespace(logdir=str(tmp_path))  # found by _spans in this frame
+    assert tracer.logdir
+    lo, hi = T.load(str(DATA)).window()
+    assert _spans.spans({"trace": {"bounds": [lo, hi]}}) == []

@@ -7,11 +7,13 @@ spans, with a 50 ms ``bench.sleep`` before the third)."""
 from __future__ import annotations
 
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
-from bench import flops
+from bench import flops, spec
 from bench import trace as T
+from bench.families import dense_decoder
 
 DATA = Path(__file__).resolve().parent / "data" / "small_trace.xplane.pb"
 
@@ -53,19 +55,62 @@ def test_kernel_calls_and_their_least_time():
          "num_hidden_layers": 8, "hidden_act": "silu", "tie_word_embeddings": True}
     # the kernel padded 5760 to 5888 (blocks of 256): the least time is of
     # the 8 x 2304 x 5760 product it was sent
-    fl, nb = flops.kernel_cost("streamed_matmul", mm.operands, c)
+    kernels = spec.kernels()
+    fl, nb = kernels["streamed_matmul"].cost(mm.operands, c, dense_decoder)
     assert fl == 2.0 * 8 * 2304 * 5760
     assert nb == 2.0 * (8 * 2304 + 2304 * 5760 + 8 * 5760)
     peak = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     assert flops.least_seconds(fl, nb, peak) == (nb / 819e9, "memory")
     (fa,) = T.kernel_calls(t, "flash_attention")
-    fl, nb = flops.kernel_cost("flash_attention", fa.operands, c)
+    fl, nb = kernels["flash_attention"].cost(fa.operands, c, dense_decoder)
     assert fl == 4.0 * 2 * 36 * 64 * 2048 * 2049 / 2
     assert flops.least_seconds(fl, nb, peak)[1] == "compute"
     top = T.top_ops(t)
     assert top[0] == ["streamed_matmul bf16[8,5760]", 1.5]
     assert sorted(top[1:]) == [["copy bf16[4]", 1.0], ["flash_attention bf16[2,36,2048,64]", 1.0],
                                ["fusion f32[8]", 1.0]]
+
+
+def test_an_op_counts_its_self_time_and_not_that_of_the_ops_nested_in_it():
+    # a scan's while [0, 10] runs a fusion [1, 3] and a kernel call [4, 8]
+    # holding a nested copy [5, 6]; another while [12, 14] runs one fusion
+    loop = "%while.3 = (s32[], f32[8]{0}) while((s32[], f32[8]{0}) %t), body=%b"
+    ops = [(0.0, 10.0, loop), (1.0, 3.0, "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %x)"),
+           (4.0, 8.0, MATMUL), (5.0, 6.0, "%copy.2 = bf16[4]{0} copy(bf16[4]{0} %y)"),
+           (12.0, 14.0, loop), (12.5, 13.0, "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %x)")]
+    t = T.Trace(devices=[ops], spans=[(0.0, 20.0, "bench.window")])
+    assert T.top_ops(t) == [["while (s32[],", (10 - 2 - 4) + (2 - 0.5)],
+                            ["streamed_matmul bf16[8,5760]", 3.0],
+                            ["fusion f32[8]", 2.5], ["copy bf16[4]", 1.0]]
+    # the window clips an op and what it holds alike
+    t.spans = [(0.0, 5.0, "bench.window")]
+    assert dict(T.top_ops(t)) == {"while (s32[],": 5 - 2 - 1, "fusion f32[8]": 2.0,
+                                  "streamed_matmul bf16[8,5760]": 1.0, "copy bf16[4]": 0.0}
+
+
+def plane(name, **lines):
+    return SimpleNamespace(name=name, lines=[
+        SimpleNamespace(name=k, events=[SimpleNamespace(name=n, start_ns=s, duration_ns=d)
+                                        for n, s, d in v]) for k, v in lines.items()])
+
+
+def test_load_keeps_the_program_spans_and_names_idle_gaps_by_them(monkeypatch):
+    import jax
+
+    ms = 1_000_000
+    device = plane("/device:TPU:0", **{"XLA Ops": [("%a = f32[1] add()", 0, 2 * ms),
+                                                   ("%a = f32[1] add()", 8 * ms, 2 * ms)]})
+    host = plane("/host:CPU", python=[
+        ("bench.window", 0, 10 * ms), ("bench.step_segment", 1 * ms, 8 * ms),
+        ("engine.segment", 1 * ms, 8 * ms), ("engine.join#rid=3,prompt_len=8#", 2 * ms, 5 * ms),
+        ("other.span", 3 * ms, 1 * ms)])
+    monkeypatch.setattr(jax.profiler.ProfileData, "from_file",
+                        staticmethod(lambda path: SimpleNamespace(planes=[device, host])))
+    t = T.load("unused")
+    assert [n for _, _, n in t.spans] == ["bench.window", "bench.step_segment",
+                                         "engine.segment", "engine.join"]
+    # the one gap, [2, 8] ms, is named by the program's join open at its middle
+    assert T.idle_breakdown(t) == [["engine.join", pytest.approx(6e-3)]]
 
 
 def test_an_async_copy_feeds_from_its_issue_to_its_done():

@@ -15,7 +15,9 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import flops, serve, spec, train
+from bench import spec
+from bench.drivers import serve, train
+from bench.families import dense_decoder as dense
 from bench.metrics import _shared
 from bench.traffic.batches import Batches
 from bench.traffic.requests import Requests
@@ -140,15 +142,15 @@ def test_leaf_gap_takes_the_worst_leaf_against_the_median():
 
 def test_model_flops_of_the_configurations():
     c = spec.cell("minicpm-2b.pretrain").config
-    n = flops.matmul_params(c)
+    n = dense.matmul_params(c)
     assert n == 8 * 61_046_784 + 2304 * 122753           # 771 M with the tied head
-    step = flops.train_token_flops(c, 2048) * 2 * 2048
+    step = dense.train_token_flops(c, 2048) * 2 * 2048
     assert step == pytest.approx(20.8e12, rel=0.01)
     s = spec.cell("starcoder2-15b.code-complete-unrolled").config
-    assert flops.matmul_params(s) == pytest.approx(3.674e9 - 49152 * 6144, rel=0.002)
-    assert flops.prefill_flops(s, 1) == flops.serve_token_flops(s, 1)
-    assert flops.decode_flops(s, 10, 2) == pytest.approx(
-        flops.serve_token_flops(s, 11) + flops.serve_token_flops(s, 12))
+    assert dense.matmul_params(s) == pytest.approx(3.674e9 - 49152 * 6144, rel=0.002)
+    assert dense.prefill_flops(s, 1) == dense.serve_token_flops(s, 1)
+    assert dense.decode_flops(s, 10, 2) == pytest.approx(
+        dense.serve_token_flops(s, 11) + dense.serve_token_flops(s, 12))
 
 
 def test_mfu_idle_and_roofline_readers():
@@ -220,9 +222,9 @@ def test_a_traced_run_reads_its_metrics_over_the_traced_part(monkeypatch):
     seen = {}
     reduce = R.reduce_trace
 
-    def spy(path, c, rec, peak):
+    def spy(path, cell, rec, peak):
         seen.update(rec)
-        return reduce(path, c, rec, peak)
+        return reduce(path, cell, rec, peak)
 
     monkeypatch.setattr(R, "reduce_trace", spy)
     monkeypatch.setattr(peaks, "peaks", lambda kind: peaks.PEAKS["TPU v5 lite"])

@@ -1,27 +1,18 @@
-"""Cells of BENCHMARK.json shrunk to the program's smoke widths, for runs on
-the CPU: every setting of the real cell, with the model, the pool and the
-traffic cut to a size a test can hold."""
+"""Cells of BENCHMARK.json shrunk to their family's smoke widths
+(``smoke(c)`` of ``bench/families/<family>.py``), for runs on the CPU: every
+setting of the real cell, with the model, the pool and the traffic cut to a
+size a test can hold."""
 
 from __future__ import annotations
 
-import copy
-
 from bench import spec
-
-SMOKE = {
-    "minicpm-2b": dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
-                       head_dim=16, intermediate_size=128, vocab_size=256),
-    "starcoder2-15b": dict(hidden_size=64, num_attention_heads=8, num_key_value_heads=2,
-                           head_dim=8, intermediate_size=128, vocab_size=256),
-}
 
 
 def cell(name: str, layers: int = 2) -> spec.Cell:
-    c = copy.deepcopy(spec.cell(name))
-    c.config.update(SMOKE[c.config["registry"]], num_hidden_layers=layers,
-                    registry_smoke=True)
+    c = spec.cell(name)
+    c.config.update(c.family.smoke(c.config), num_hidden_layers=layers)
     w = c.workload
-    if w["driver"] == "serve":
+    if "engine" in w:
         w["engine"].update(max_lanes=4, pool_seq=64, segment_len=8)
         w["check"].update(requests=3, group=2)
         c.traffic.update(prompt_len={"ladder": [8, 16], "p": [0.5, 0.5]},

@@ -8,10 +8,12 @@ bf16[512,256]{...} %b), custom_call_target="tpu_custom_call", ...``. A
 Pallas kernel's instruction carries the name of the jitted function that
 wraps its ``pallas_call`` (``streamed_matmul``, ``flash_attention``), and its
 operands' shapes. Each run of a compiled program is an event on the
-``XLA Modules`` line. The harness's own spans (``bench.*``, from
-``jax.profiler.TraceAnnotation``) are events on the host plane, on the same
+``XLA Modules`` line. The harness's own spans (``bench.*``) and the
+program's (``engine.*``, ``runtime.*``), all from
+``jax.profiler.TraceAnnotation``, are events on the host plane, on the same
 clock to within about a millisecond; ``bench.traced`` marks the part of the
-window that was traced.
+window that was traced. An op that runs others inside it (a scan's
+``while``, a ``conditional``) is an event that encloses theirs.
 """
 
 from __future__ import annotations
@@ -23,6 +25,7 @@ import re
 _OP = re.compile(r"^%([A-Za-z_][\w-]*?)(?:\.\d+)? = (\S+?)(?:\{[^}]*\})? ")
 _KERNEL = re.compile(r"^%([A-Za-z_]\w*?)(?:\.\d+)? = .*?custom-call\((.*?)\), "
                      r"custom_call_target=\"tpu_custom_call\"")
+SPANS = ("bench.", "engine.", "runtime.")
 _ARG = re.compile(r"\b(bf16|f32|f16|s32|u32|s8|u8|pred)\[([\d,]*)\](\{[^}]*\})? %([\w.-]+)")
 
 
@@ -42,7 +45,7 @@ class KernelCall:
 class Trace:
     """What one traced window holds."""
     devices: list[list[tuple[float, float, str]]]  # per device: (start s, end s, op)
-    spans: list[tuple[float, float, str]]          # harness spans: (start, end, name)
+    spans: list[tuple[float, float, str]]          # host spans: (start, end, name)
     copies: list[list[tuple[float, float, str]]] = dataclasses.field(default_factory=list)
     # per device, the asynchronous copies (``Async XLA Ops``)
     modules: list[list[tuple[float, float, str]]] = dataclasses.field(default_factory=list)
@@ -74,9 +77,10 @@ def load(path: str) -> Trace:
             copies.append(lines.get("Async XLA Ops", []))
             modules.append(lines.get("XLA Modules", []))
         elif plane.name.startswith("/host:"):
-            spans += [(ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9, ev.name)
+            spans += [(ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9,
+                       ev.name.partition("#")[0])
                       for line in plane.lines for ev in line.events
-                      if ev.name.startswith("bench.")]
+                      if ev.name.startswith(SPANS)]
     return Trace(devices=devices, spans=sorted(spans), copies=copies, modules=modules)
 
 
@@ -121,7 +125,8 @@ def gaps(trace: Trace, device: int = 0) -> list[tuple[float, float]]:
 
 
 def open_span(trace: Trace, t: float) -> str:
-    """The innermost harness span open at instant ``t``."""
+    """The innermost span open at instant ``t``, the harness's or the
+    program's."""
     best, width = "none", float("inf")
     for s, e, n in trace.spans:
         if s <= t <= e and e - s < width:
@@ -130,8 +135,8 @@ def open_span(trace: Trace, t: float) -> str:
 
 
 def idle_breakdown(trace: Trace, n: int = 10) -> list[list]:
-    """The ``n`` longest idle gaps of device 0, each named by the harness span
-    open at its middle."""
+    """The ``n`` longest idle gaps of device 0, each named by the innermost
+    span open at its middle."""
     if not trace.devices:
         return []
     g = sorted(gaps(trace), key=lambda iv: iv[0] - iv[1])[:n]
@@ -145,13 +150,22 @@ def op_key(name: str) -> str:
 
 
 def top_ops(trace: Trace, n: int = 10) -> list[list]:
-    """The ``n`` op kinds that took most device time in the window, device 0."""
+    """The ``n`` op kinds that took most device time in the window, device 0,
+    each op by its self time: its own time less that of the ops nested in
+    it, so that a scan's ``while`` does not count its body again."""
     lo, hi = trace.window()
     acc: dict[str, float] = {}
-    for s, e, name in (trace.devices or [[]])[0]:
-        if e > lo and s < hi:
-            k = op_key(name)
-            acc[k] = acc.get(k, 0.0) + (min(e, hi) - max(s, lo))
+    open_: list[tuple[float, str]] = []        # (end, kind) of the enclosing ops
+    for s, e, name in sorted((trace.devices or [[]])[0], key=lambda op: (op[0], -op[1])):
+        while open_ and open_[-1][0] <= s:
+            open_.pop()
+        own = max(0.0, min(e, hi) - max(s, lo))
+        k = op_key(name)
+        acc[k] = acc.get(k, 0.0) + own
+        if open_ and e <= open_[-1][0]:      # nested in the op below it
+            parent = open_[-1][1]
+            acc[parent] = acc.get(parent, 0.0) - own
+        open_.append((e, k))
     return [[k, v] for k, v in sorted(acc.items(), key=lambda kv: -kv[1])[:n]]
 
 
